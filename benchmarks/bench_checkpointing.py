@@ -56,6 +56,7 @@ from repro.ckpt.restore import (
 from repro.ckpt.saver import AsyncSaver, snapshot_state, write_distributed
 from repro.core.layout import MeshSpec
 from repro.dist.sharding import make_plan, vocab_multiple
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.train.trainer import Trainer
 
@@ -178,7 +179,7 @@ def bench_transform_load(
     tgt_mesh = default_mesh(2, 2)
     mix_mesh = default_mesh(4, 1)  # TP 2 -> 1: fused params consolidate
     parallel = ParallelismConfig()
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     for size in sizes:
         cfg, lm, plan_src, state = build_sized(size, src_mesh, parallel)
         plan_tgt = make_plan(cfg, lm.registry, parallel, tgt_mesh)
@@ -311,7 +312,7 @@ def bench_hot_tier(sizes=("small", "medium")) -> list[tuple[str, float, str]]:
     src_mesh = default_mesh(4, 2)
     tgt_mesh = default_mesh(2, 2)
     parallel = ParallelismConfig()
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     for size in sizes:
         cfg, lm, plan_src, state = build_sized(size, src_mesh, parallel)
         plan_tgt = make_plan(cfg, lm.registry, parallel, tgt_mesh)
@@ -421,7 +422,7 @@ def bench_delta(sizes=("small", "medium")) -> list[tuple[str, float, str]]:
     mesh = default_mesh(4, 2)
     tgt_mesh = default_mesh(2, 2)
     parallel = ParallelismConfig()
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     for size in sizes:
         cfg, lm, plan, state = build_sized(size, mesh, parallel)
         plan_tgt = make_plan(cfg, lm.registry, parallel, tgt_mesh)
@@ -579,7 +580,7 @@ def bench_codec(sizes=("small", "medium")) -> list[tuple[str, float, str]]:
     rows = []
     mesh = default_mesh(4, 2)
     parallel = ParallelismConfig()
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     # the 0.35x target is for the all-coded checkpoint (explicit lossy-params
     # opt-in); the bit-identity row uses the default lossless-params policy
     all_int8 = CodecPolicy(params="int8:b256", exp_avg="int8:b256",
@@ -702,7 +703,7 @@ def bench_codec_equiv() -> list[tuple[str, float, str]]:
     tcfg = TrainConfig(warmup_steps=2, total_steps=100)
 
     def trainer(tmp, save_interval=8, codec=None):
-        jm = jax.make_mesh((1, 1), ("data", "model"))
+        jm = make_mesh((1, 1), ("data", "model"))
         pol = CheckpointPolicy(
             save_interval=save_interval, async_save=False, codec=codec
         )
@@ -762,7 +763,7 @@ def bench_correctness() -> list[tuple[str, float, str]]:
     def trainer(tmp, save_interval=8, **kw):
         from repro.ckpt.policy import CheckpointPolicy
 
-        jm = jax.make_mesh((1, 1), ("data", "model"))
+        jm = make_mesh((1, 1), ("data", "model"))
         pol = CheckpointPolicy(save_interval=save_interval, async_save=False)
         return Trainer.create(
             cfg, ParallelismConfig(**kw), tcfg, jm, batch_size=4, seq_len=24,

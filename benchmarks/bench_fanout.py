@@ -36,6 +36,7 @@ from repro.core.dist_ckpt import DistCheckpoint
 from repro.core.layout import MeshSpec
 from repro.core.pytree import flatten_with_paths
 from repro.dist.sharding import ShardingPlan
+from repro.launch.mesh import make_mesh
 from repro.serve import FanoutStats, FleetReplica, PublicationRegistry
 
 READER_COUNTS = (1, 8, 32)
@@ -64,7 +65,7 @@ def bench_fanout(sizes=("small", "medium")) -> list[tuple[str, float, str]]:
     mesh = default_mesh()
     parallel = ParallelismConfig()
     decode_mesh = MeshSpec.from_dict({"data": 1, "model": 1})
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     for size in sizes:
         cfg, lm, plan, state = build_sized(size, mesh, parallel)
         snap = snapshot_state(state)

@@ -70,6 +70,10 @@ def main() -> None:
     )
     args = p.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     tracer = None
     if args.trace:
         import repro.obs as obs

@@ -72,6 +72,7 @@ def hot_tier_demo() -> None:
     from repro.ckpt.manager import CheckpointManager
     from repro.dist.sharding import make_plan, vocab_multiple
     from repro.elastic.resume import ElasticEvent, hot_recover
+    from repro.launch.mesh import make_mesh
     from repro.models import build_model
     from repro.train.optimizer import init_state
 
@@ -81,7 +82,7 @@ def hot_tier_demo() -> None:
     lm = build_model(cfg, vocab_multiple=vocab_multiple(parallel, mesh))
     plan = make_plan(cfg, lm.registry, parallel, mesh)
     state = init_state(lm.init(jax.random.PRNGKey(0)))
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
 
     with tempfile.TemporaryDirectory() as tmp:
         mgr = CheckpointManager(

@@ -31,6 +31,7 @@ from repro.core import DistCheckpoint, MeshSpec
 from repro.core.engine import CheckpointEngine
 from repro.core.pytree import flatten_with_paths
 from repro.dist.sharding import ShardingPlan
+from repro.launch.mesh import make_mesh
 from repro.serve import FanoutStats, FleetReplica, PublicationRegistry
 from repro.train.trainer import Trainer
 
@@ -53,7 +54,7 @@ def main() -> None:
     registry = PublicationRegistry(name="demo")
 
     with tempfile.TemporaryDirectory() as tmp:
-        train_mesh = jax.make_mesh((2, 2), ("data", "model"))
+        train_mesh = make_mesh((2, 2), ("data", "model"))
         trainer = Trainer.create(
             cfg, ParallelismConfig(), TrainConfig(warmup_steps=2),
             train_mesh, batch_size=8, seq_len=32,
@@ -76,7 +77,7 @@ def main() -> None:
             mesh=MeshSpec.from_dict({"data": 1, "model": 1}),
             param_specs=trainer.plan.param_specs,
         )
-        decode_jmesh = jax.make_mesh((1, 1), ("data", "model"))
+        decode_jmesh = make_mesh((1, 1), ("data", "model"))
         engine = CheckpointEngine(workers=4)
         stats = FanoutStats()
         replicas = [

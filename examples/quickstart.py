@@ -18,6 +18,7 @@ from repro.core.atoms import UcpCheckpoint
 from repro.core.convert import convert_to_ucp
 from repro.core.dist_ckpt import DistCheckpoint
 from repro.core.patterns import StateKind
+from repro.launch.mesh import make_mesh
 from repro.train.trainer import Trainer
 
 
@@ -26,7 +27,7 @@ def main() -> None:
     print(f"model: {cfg.name}  layers={cfg.num_layers} d={cfg.d_model}")
 
     with tempfile.TemporaryDirectory() as tmp:
-        jmesh = jax.make_mesh((1, 1), ("data", "model"))
+        jmesh = make_mesh((1, 1), ("data", "model"))
         trainer = Trainer.create(
             cfg, ParallelismConfig(), TrainConfig(warmup_steps=2),
             jmesh, batch_size=4, seq_len=32,

@@ -46,7 +46,6 @@ import threading
 from pathlib import Path
 from typing import Any
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -58,6 +57,7 @@ from repro.core import clock
 from repro.core.engine import CheckpointEngine
 from repro.dist.sharding import ShardingPlan
 from repro.elastic.resume import ElasticEvent, hot_recover
+from repro.launch.mesh import make_mesh
 from repro.serve import FleetReplica, PublicationRegistry
 from repro.train.optimizer import TrainState
 
@@ -192,7 +192,7 @@ class ChaosHarness:
         self.specs = _specs()
         self.plan = ShardingPlan(mesh=MESH_2X2, param_specs=self.specs)
         self.tgt_plan = ShardingPlan(mesh=MESH_1X1, param_specs=self.specs)
-        self.jmesh = jax.make_mesh((1, 1), ("data", "model"))
+        self.jmesh = make_mesh((1, 1), ("data", "model"))
         self.registry = PublicationRegistry(name=f"chaos{seed}")
         self.replica_engine = CheckpointEngine(workers=1)
         self.replica: FleetReplica | None = None

@@ -51,7 +51,13 @@ from repro.core.tensor_io import IntegrityError
 from repro.dist.sharding import ShardingPlan
 from repro.train.optimizer import TrainState
 from .policy import CheckpointPolicy, policy_from_legacy_kwargs
-from .restore import RestoreStats, state_from_dist, state_from_stream, state_from_ucp
+from .restore import (
+    RestoreStats,
+    params_from_source,
+    state_from_dist,
+    state_from_stream,
+    state_from_ucp,
+)
 from .saver import AsyncSaver, SaveResult, snapshot_state, write_distributed
 
 __all__ = ["CheckpointManager", "RestoreInfo"]
@@ -537,6 +543,52 @@ class CheckpointManager:
             return self._restore_traced(
                 sw, plan, jmesh, step, convert_workers, verify, force_mode
             )
+
+    def restore_params(
+        self, jmesh: jax.sharding.Mesh, *, step: int | None = None
+    ) -> tuple[Any, RestoreInfo] | None:
+        """Weights-only restore for serving: the params pytree of ``step``
+        (default: newest) on ``jmesh`` under the manager's plan.
+
+        DIRECT and RESHARD_STREAM layouts read only the FP32 state — a
+        third of a training restore's bytes.  Any other plan (a changed
+        parameter set, an unstreamable change) takes the full
+        :meth:`restore` ladder and keeps its params.  Returns None when no
+        committed checkpoint exists."""
+        step = step if step is not None else self.latest_step()
+        if step is None:
+            return None
+        ckpt = DistCheckpoint.open(self.step_dir(step))
+        target = TargetSpec(self.plan.mesh, self.plan.param_specs)
+        rp = plan_resume(ckpt.manifest, target)
+        if rp.mode not in (ResumeMode.DIRECT, ResumeMode.RESHARD_STREAM):
+            state, info = self.restore(jmesh, step=step)
+            return state.params, info
+        transforms = (
+            None
+            if rp.mode is ResumeMode.DIRECT
+            else rp.transforms or stream_transforms(ckpt.manifest, target)
+        )
+        with obs.timed(
+            "ckpt.restore", step=step, mode=rp.mode.value, reason=rp.reason
+        ) as sw:
+            stats = RestoreStats()
+            with obs.span("restore.tier", tier=rp.mode.value):
+                params = params_from_source(
+                    ckpt, self.plan, jmesh, stats,
+                    transforms=transforms, engine=self.engine,
+                )
+            obs.add("restore.count")
+        info = RestoreInfo(
+            step=step,
+            mode=rp.mode,
+            reason=rp.reason,
+            scalars=dict(ckpt.manifest.scalars),
+            convert_stats=None,
+            restore_stats=stats,
+            wall_time_s=sw.elapsed_s,
+        )
+        return params, info
 
     def _restore_traced(
         self, sw, plan, jmesh, step, convert_workers, verify, force_mode

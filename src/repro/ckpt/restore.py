@@ -36,7 +36,7 @@ from typing import Mapping
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 import repro.obs as obs
 from repro.core.atoms import UcpCheckpoint
@@ -243,7 +243,9 @@ def _build_state(
         params=unflatten_from_paths(trees["params"]),
         exp_avg=unflatten_from_paths(trees["exp_avg"]),
         exp_avg_sq=unflatten_from_paths(trees["exp_avg_sq"]),
-        step=jnp.asarray(step, jnp.int32),
+        # Replicated over the mesh like the trainer's own step counter, not
+        # committed to the default device alone.
+        step=jax.device_put(jnp.asarray(step, jnp.int32), NamedSharding(jmesh, P())),
     )
 
 

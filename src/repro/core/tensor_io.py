@@ -19,18 +19,14 @@ import os
 import zlib
 from pathlib import Path
 
+import ml_dtypes
 import numpy as np
 
-try:  # ml_dtypes ships with jax; core stays importable without it.
-    import ml_dtypes
-
-    _EXTENDED: dict[str, np.dtype] = {
-        "bfloat16": np.dtype(ml_dtypes.bfloat16),
-        "float8_e4m3fn": np.dtype(ml_dtypes.float8_e4m3fn),
-        "float8_e5m2": np.dtype(ml_dtypes.float8_e5m2),
-    }
-except ImportError:  # pragma: no cover
-    _EXTENDED = {}
+_EXTENDED: dict[str, np.dtype] = {
+    "bfloat16": np.dtype(ml_dtypes.bfloat16),
+    "float8_e4m3fn": np.dtype(ml_dtypes.float8_e4m3fn),
+    "float8_e5m2": np.dtype(ml_dtypes.float8_e5m2),
+}
 
 __all__ = [
     "IntegrityError",

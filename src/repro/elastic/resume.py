@@ -35,6 +35,7 @@ import dataclasses
 import jax
 
 from repro.configs.base import ModelConfig, ParallelismConfig, TrainConfig
+from repro.launch.mesh import make_mesh
 from repro.train.trainer import Trainer
 from .planner import propose_mesh
 
@@ -72,7 +73,7 @@ def rebuild_on(
     """
     mesh_spec = propose_mesh(cfg, event.healthy_devices,
                              moment_dtype=parallel.moment_dtype)
-    jmesh = jax.make_mesh(mesh_spec.shape, mesh_spec.axis_names)
+    jmesh = make_mesh(mesh_spec.shape, mesh_spec.axis_names)
     return Trainer.create(
         cfg, parallel, tcfg, jmesh,
         batch_size=batch_size, seq_len=seq_len, ckpt_dir=ckpt_dir,

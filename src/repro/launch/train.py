@@ -13,8 +13,10 @@ Examples::
         --host-devices 8 --mesh data=8,model=1 --steps 20 --batch 8 --seq 64 \
         --ckpt-dir /tmp/run1
 
-``--host-devices`` must be applied before jax initializes, hence the
-environment mutation at the very top of ``main`` and all deferred imports.
+``--host-devices N`` is a CPU simulation on every machine: it selects the
+CPU backend and gives it N devices.  Both must be applied before jax
+initializes, hence the environment mutation at the very top of ``main``
+and all deferred imports.
 ``--log-json`` emits one JSON object per step on stdout (consumed by the
 e2e reconfiguration tests and the correctness benchmark).
 """
@@ -32,8 +34,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--arch", required=True)
     p.add_argument("--reduced", action="store_true", help="tiny same-family config")
     p.add_argument("--host-devices", type=int, default=0,
-                   help="simulate N CPU devices (sets XLA_FLAGS; must be set "
-                        "before jax init)")
+                   help="simulate N devices on the CPU (sets JAX_PLATFORMS=cpu "
+                        "and XLA_FLAGS before jax init), also on a machine "
+                        "with an accelerator")
     p.add_argument("--mesh", default="data=1,model=1")
     p.add_argument("--steps", type=int, default=20)
     p.add_argument("--batch", type=int, default=8)
@@ -81,13 +84,20 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+def simulate_host_devices(n: int) -> None:
+    """Run on ``n`` simulated CPU devices, even where an accelerator is the
+    default backend.  Must run before jax initializes."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={n} "
+        + os.environ.get("XLA_FLAGS", "")
+    )
+
+
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     if args.host_devices:
-        flags = os.environ.get("XLA_FLAGS", "")
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.host_devices} " + flags
-        )
+        simulate_host_devices(args.host_devices)
 
     # obs is jax-free, safe to import before XLA_FLAGS matters
     import repro.obs as obs
@@ -102,13 +112,17 @@ def main(argv=None) -> int:
 
 
 def _run(args) -> int:
-    # jax-dependent imports only after XLA_FLAGS is final
+    # jax-dependent imports only after JAX_PLATFORMS/XLA_FLAGS are final
+    import jax
+
     from repro.configs import ParallelismConfig, TrainConfig, get_config, reduced
     from repro.ckpt.policy import CheckpointPolicy
     from repro.core.codec import CodecPolicy
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh_from_string
     from repro.train.trainer import Trainer
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
@@ -163,7 +177,7 @@ def _run(args) -> int:
         policy=policy,
     )
     state, info = trainer.init_or_restore()
-    start = int(jax.device_get(state.step)) if (jax := __import__("jax")) else 0
+    start = int(jax.device_get(state.step))
     if info is not None:
         print(
             json.dumps(

@@ -199,6 +199,9 @@ class Trainer:
             for step in range(start_step, start_step + num_steps):
                 with obs.timed("train.step", step=step + 1) as sw:
                     state, metrics = self.step_fn(state, self.batch(step))
+                    # jit returns at dispatch: the step ends when the
+                    # device has produced its outputs.
+                    jax.block_until_ready((state, metrics))
                 rec = {
                     "step": step + 1,
                     "loss": float(metrics["loss"]),

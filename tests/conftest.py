@@ -8,14 +8,3 @@ assert "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS",
 )
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-# The container image may lack `hypothesis`; fall back to the minimal
-# API-compatible stub so the property tests run as seeded randomized tests.
-# The real package always wins when installed.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    from repro._compat import hypothesis_stub
-
-    sys.modules["hypothesis"] = hypothesis_stub
-    sys.modules["hypothesis.strategies"] = hypothesis_stub.strategies

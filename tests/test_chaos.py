@@ -36,6 +36,7 @@ from repro.core import (
 )
 from repro.core import clock
 from repro.dist.sharding import ShardingPlan
+from repro.launch.mesh import make_mesh
 from repro.serve import FleetReplica, PublicationRegistry
 from repro.train.optimizer import TrainState
 
@@ -97,7 +98,7 @@ def setup(tmp_path):
     specs = _specs()
     plan = ShardingPlan(mesh=MESH_2X2, param_specs=specs)
     tgt_plan = ShardingPlan(mesh=MESH_1X1, param_specs=specs)
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     return tmp_path, plan, tgt_plan, jmesh
 
 

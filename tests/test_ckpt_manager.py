@@ -16,6 +16,7 @@ from repro.core.plan import ResumeMode
 from repro.ckpt.manager import CheckpointManager
 from repro.ckpt.saver import AsyncSaver, snapshot_state, write_distributed
 from repro.dist.sharding import make_plan, vocab_multiple
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.train.optimizer import init_state
 
@@ -28,7 +29,7 @@ def setup(tmp_path):
     lm = build_model(cfg, vocab_multiple=vocab_multiple(parallel, mesh))
     plan = make_plan(cfg, lm.registry, parallel, mesh)
     state = init_state(lm.init(jax.random.PRNGKey(0)))
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     return tmp_path, cfg, lm, plan, state, jmesh
 
 
@@ -115,6 +116,30 @@ def test_reshard_stream_restore_writes_nothing(setup):
     # zero intermediate bytes: the checkpoint directory is untouched
     assert before == sorted(p for p in (tmp / "ck").rglob("*") if p.is_file())
     _state_equal(state, restored)
+
+
+@pytest.mark.parametrize("zero1", [False, True], ids=["direct", "reshard_stream"])
+def test_restore_params_reads_weights_only(setup, zero1):
+    """The serving restore gives the full restore's params from a third of
+    its reads, on the same and on a changed layout."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    tmp, cfg, lm, plan, state, jmesh = setup
+    CheckpointManager(tmp / "ck", plan, async_save=False).save(state, 10)
+    if zero1:
+        parallel2 = ParallelismConfig(zero=1, fsdp=False)
+        lm2 = build_model(cfg, vocab_multiple=vocab_multiple(parallel2, plan.mesh))
+        plan = make_plan(cfg, lm2.registry, parallel2, plan.mesh)
+    reader = CheckpointManager(tmp / "ck", plan, async_save=False)
+    params, info = reader.restore_params(jmesh)
+    full, full_info = reader.restore(jmesh)
+    want = ResumeMode.RESHARD_STREAM if zero1 else ResumeMode.DIRECT
+    assert info.mode == full_info.mode == want and info.step == 10
+    assert 3 * info.restore_stats.bytes_read == full_info.restore_stats.bytes_read
+    for x, y in zip(jax.tree.leaves(params), jax.tree.leaves(full.params), strict=True):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    # the restored step counter is placed on the mesh like the trainer's
+    assert full.step.sharding.is_equivalent_to(NamedSharding(jmesh, P()), 0)
 
 
 def test_via_ucp_restore_and_conversion_cache(setup):

@@ -54,6 +54,7 @@ from repro.kernels.block_quant import (
     dequantize_blocks,
     quantize_blocks,
 )
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.serve import PeerFragmentSource, PublicationRegistry
 from repro.train.optimizer import TrainState, init_state
@@ -326,7 +327,7 @@ def test_coded_full_save_direct_restore(tmp_path):
     for key, t in ckpt.manifest.shard_codecs.items():
         assert t == tag and "@fp32" not in key
     assert ckpt.validate() == []  # served digests verify coded shards
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     st = state_from_dist(ckpt, plan, jmesh)
     for n in specs:
         np.testing.assert_array_equal(
@@ -363,7 +364,7 @@ def test_int8ef_params_bit_identical(tmp_path):
     )
     ckpt = DistCheckpoint.open(tmp_path / "step_1")
     assert ckpt.validate() == []
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     st = state_from_dist(ckpt, plan, jmesh)
     for n in specs:
         np.testing.assert_array_equal(
@@ -385,7 +386,7 @@ def test_coded_reshard_and_peer_fanout(tmp_path):
     )
     ckpt = DistCheckpoint.open(tmp_path / "step_1")
     tgt_plan = ShardingPlan(mesh=MESH_1X1, param_specs=specs)
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     st = state_from_dist(ckpt, tgt_plan, jmesh)
     for n in specs:
         np.testing.assert_array_equal(
@@ -442,7 +443,7 @@ def test_coded_delta_chain_inherits_and_diffs_on_pre_digests(tmp_path):
     # chain restore == coded full save of the same final state
     write_distributed(snap2, plan, 2, tmp_path / "full_2", codec=codec)
     full = DistCheckpoint.open(tmp_path / "full_2")
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     st_chain = state_from_dist(ck2, plan, jmesh)
     st_full = state_from_dist(full, plan, jmesh)
     for a, b in zip(jax.tree.leaves(st_chain.exp_avg), jax.tree.leaves(st_full.exp_avg)):
@@ -472,7 +473,7 @@ def model_setup(tmp_path):
     )
     state = TrainState(state.params, rand(state.exp_avg),
                        rand(state.exp_avg_sq), state.step)
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     return tmp_path, cfg, plan, state, jmesh
 
 

@@ -10,6 +10,7 @@ from repro.dist.collectives import (
     dequantize_int8,
     quantize_int8,
 )
+from repro.launch.mesh import make_mesh
 
 
 def test_quantize_roundtrip_error_bound():
@@ -31,10 +32,9 @@ def test_quantize_handles_zeros_and_padding():
 def test_error_feedback_unbiased_over_steps():
     """With error feedback, the *accumulated* synced gradient converges to
     the accumulated true gradient (compression noise does not build up)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
 
     true_total = jnp.zeros((64,))
     sync_total = jnp.zeros((64,))
@@ -43,7 +43,7 @@ def test_error_feedback_unbiased_over_steps():
 
     @jax.jit
     def step(g, err):
-        f = shard_map(
+        f = jax.shard_map(
             lambda gg, ee: compressed_psum(gg, ee, axis_name="pod"),
             mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
         )

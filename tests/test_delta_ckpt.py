@@ -29,6 +29,7 @@ from repro.core.pytree import flatten_with_paths, unflatten_from_paths
 from repro.ckpt.manager import CheckpointManager
 from repro.ckpt.saver import snapshot_state, write_distributed
 from repro.dist.sharding import make_plan, vocab_multiple
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.train.optimizer import TrainState, init_state
 
@@ -41,7 +42,7 @@ def setup(tmp_path):
     lm = build_model(cfg, vocab_multiple=vocab_multiple(parallel, mesh))
     plan = make_plan(cfg, lm.registry, parallel, mesh)
     state = init_state(lm.init(jax.random.PRNGKey(0)))
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     return tmp_path, cfg, plan, state, jmesh
 
 

@@ -26,6 +26,7 @@ from repro.core import (
     uniform_param_spec,
 )
 from repro.dist.sharding import ShardingPlan
+from repro.launch.mesh import make_mesh
 
 
 def _plan(mesh, specs) -> ShardingPlan:
@@ -154,7 +155,7 @@ def test_state_from_dist_parallel_equals_serial(tmp_path):
     write_distributed(snap, _plan(src_mesh, src_specs), 3, tmp_path / "ck", workers=4)
     ck = DistCheckpoint.open(tmp_path / "ck")
 
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     tgt_plan = _plan(tgt_mesh, tgt_specs)
     with CheckpointEngine(workers=1) as e1, CheckpointEngine(workers=4) as e4:
         s1 = state_from_dist(ck, tgt_plan, jmesh, engine=e1)

@@ -28,6 +28,7 @@ from repro.ckpt.restore import (
 from repro.ckpt.saver import write_distributed
 from repro.dist.sharding import ShardingPlan
 from repro.hot import binomial_parent, fanout_ladder
+from repro.launch.mesh import make_mesh
 from repro.serve import (
     FanoutStats,
     FleetReplica,
@@ -73,7 +74,7 @@ def published(tmp_path):
     registry = PublicationRegistry()
     pub = registry.publish(ckpt)
     tgt_plan = ShardingPlan(mesh=MESH_1X1, param_specs=specs)
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     return tmp_path, plan, snap, ckpt, registry, pub, tgt_plan, jmesh
 
 
@@ -243,7 +244,7 @@ def test_fanout_consolidation_assembled_once_per_fleet(tmp_path):
     registry = PublicationRegistry()
     registry.publish(ckpt)
     tgt_plan = ShardingPlan(mesh=MESH_1X1, param_specs=specs)
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     engine = CheckpointEngine(workers=2)
     reps = [
         FleetReplica(f"c{i}", registry, tgt_plan, jmesh, engine=engine)
@@ -423,7 +424,7 @@ def test_crash_mid_publish_fleet_still_serves(tmp_path):
     specs = _specs()
     plan = ShardingPlan(mesh=MESH_2X2, param_specs=specs)
     tgt_plan = ShardingPlan(mesh=MESH_1X1, param_specs=specs)
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
 
     def state_at(seed):
         snap = _random_state(specs, seed=seed)
@@ -487,7 +488,7 @@ def test_concurrent_readers_one_engine_stress(tmp_path):
     write_distributed(snap, plan, 5, tmp_path / "step_5")
     ckpt = DistCheckpoint.open(tmp_path / "step_5")
     tgt_plan = ShardingPlan(mesh=MESH_1X1, param_specs=specs)
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     engine = CheckpointEngine(workers=4)
     ref = state_from_dist(ckpt, tgt_plan, jmesh, engine=CheckpointEngine(workers=1))
 

@@ -27,6 +27,7 @@ from repro.hot import (
     plan_hot_recovery,
     state_from_hot,
 )
+from repro.launch.mesh import make_mesh
 
 
 def _plan(mesh, specs) -> ShardingPlan:
@@ -134,7 +135,7 @@ def test_hot_direct_and_reshard_bit_identical_after_rank_failure(tmp_path):
     dead = tier.fail_ranks({0, 3})
     assert dead == {}, f"replication should cover single-buddy loss: {dead}"
 
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     tgt_mesh = MeshSpec.from_dict({"data": 4, "model": 1})
     tgt_specs = {
         "w": uniform_param_spec("w", (8, 6), [DimSpec(("data",)), DimSpec()]),
@@ -359,7 +360,7 @@ def test_restore_verify_flag_raises_on_corruption(tmp_path):
     lm = build_model(cfg, vocab_multiple=vocab_multiple(parallel, mesh))
     plan = make_plan(cfg, lm.registry, parallel, mesh)
     state = init_state(lm.init(jax.random.PRNGKey(0)))
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
 
     mgr = CheckpointManager(tmp_path / "ck", plan, async_save=False)
     mgr.save(state, 10)
@@ -409,7 +410,7 @@ def test_hot_snapshot_verify_catches_in_memory_rot():
     with pytest.raises(IntegrityError):
         import jax
 
-        state_from_hot(hs, plan, jax.make_mesh((1, 1), ("data", "model")), verify=True)
+        state_from_hot(hs, plan, make_mesh((1, 1), ("data", "model")), verify=True)
     tier.clear()
 
 
@@ -444,7 +445,7 @@ def test_crash_mid_save_discovery_hot_recovery_and_gc(tmp_path, monkeypatch):
     lm = build_model(cfg, vocab_multiple=vocab_multiple(parallel, mesh))
     plan = make_plan(cfg, lm.registry, parallel, mesh)
     state = init_state(lm.init(jax.random.PRNGKey(0)))
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
 
     mgr = CheckpointManager(
         tmp_path / "ck", plan, hot_interval=5, save_interval=5, async_save=False

@@ -16,6 +16,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 import repro.obs as obs
 import repro.obs.trace as trace_mod
 from repro.configs import ParallelismConfig, get_config, reduced
@@ -46,7 +47,7 @@ def model_setup():
     lm = build_model(cfg, vocab_multiple=vocab_multiple(parallel, mesh))
     plan = make_plan(cfg, lm.registry, parallel, mesh)
     state = init_state(lm.init(jax.random.PRNGKey(0)))
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     return cfg, plan, state, jmesh
 
 

@@ -15,6 +15,7 @@ from repro.configs import ParallelismConfig, get_config, reduced
 from repro.core.codec import CodecPolicy
 from repro.core.layout import MeshSpec
 from repro.dist.sharding import make_plan, vocab_multiple
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 
 
@@ -165,7 +166,7 @@ def test_trainer_accepts_policy_and_shims_legacy(tmp_path):
 
     cfg = reduced(get_config("smollm-360m"))
     tcfg = TrainConfig(total_steps=10)
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     pol = CheckpointPolicy(save_interval=4, save_mode="delta", async_save=False)
     tr = Trainer.create(
         cfg, ParallelismConfig(), tcfg, jmesh,

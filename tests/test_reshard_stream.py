@@ -37,6 +37,7 @@ from repro.ckpt.manager import CheckpointManager
 from repro.ckpt.restore import state_from_stream, state_from_ucp
 from repro.ckpt.saver import write_distributed
 from repro.dist.sharding import ShardingPlan, make_plan, vocab_multiple
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.train.optimizer import init_state
 
@@ -70,7 +71,7 @@ def _stream_vs_ucp(tmp, src_mesh, tgt_mesh, src_specs, tgt_specs, seed=0):
     transforms = stream_transforms(
         ck.manifest, TargetSpec(tgt_mesh, dict(tgt_specs))
     )
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     s_stream = state_from_stream(ck, plan_tgt, jmesh, transforms)
     ucp, _ = convert_to_ucp(ck, str(tmp / "ucp"), workers=1)
     s_ucp = state_from_ucp(ucp, plan_tgt, jmesh)
@@ -347,7 +348,7 @@ def test_reshard_matrix(matrix_cfg, matrix_sources, tmp_path,
     lm = build_model(matrix_cfg, vocab_multiple=vocab_multiple(parallel, mesh))
     tgt_plan = make_plan(matrix_cfg, lm.registry, parallel, mesh)
     axes = tuple(tgt_mesh)
-    jmesh = jax.make_mesh((1,) * len(axes), axes)
+    jmesh = make_mesh((1,) * len(axes), axes)
 
     mgr = CheckpointManager(ck_dir, src_plan, async_save=False)
     before = sorted(p for p in ck_dir.rglob("*") if p.is_file())
@@ -408,7 +409,7 @@ def test_hot_direct_preserves_divergent_average_replicas():
     snap = _random_state({"a": spec}, seed=5)
     tier = HotTier(replication=1)
     hs, _ = tier.capture(snap, plan, 3)
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     restored = state_from_hot(hs, plan, jmesh)
     np.testing.assert_array_equal(
         np.asarray(jax.tree.leaves(restored.params)[0]),
@@ -437,7 +438,7 @@ def test_crash_mid_stream_falls_back_to_via_ucp(tmp_path, monkeypatch):
         "u": uniform_param_spec("u", (6, 4), [DimSpec(("data",)), DimSpec()]),
     }
     plan_tgt = ShardingPlan(mesh, dict(tgt))
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
 
     import repro.ckpt.restore as R
 

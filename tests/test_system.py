@@ -14,6 +14,7 @@ from repro.core.layout import MeshSpec
 from repro.core.plan import ResumeMode
 from repro.ckpt.manager import CheckpointManager
 from repro.dist.sharding import make_plan, vocab_multiple
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.models import decode as D
 from repro.train.trainer import Trainer
@@ -21,7 +22,7 @@ from repro.train.trainer import Trainer
 
 def _mk_trainer(tmp, **parallel_kw):
     cfg = reduced(get_config("smollm-360m"))
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     parallel = ParallelismConfig(**parallel_kw)
     tcfg = TrainConfig(warmup_steps=2, total_steps=50)
     return Trainer.create(
