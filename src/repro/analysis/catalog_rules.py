@@ -4,9 +4,12 @@ Cross-file accounting for the two name registries the runtime relies on:
 
 * ``fault_point("…")`` names vs :data:`repro.chaos.points.CATALOG`
 * ``obs.span/timed/event("…")`` names vs :mod:`repro.obs.catalog`
-  (``SPANS``/``TIMED``/``EVENTS``), plus literal ``obs.add``/``obs.gauge``
-  counter names vs ``COUNTERS`` (membership only — dynamic counter
-  families can't be proven covered by a literal scan)
+  (``SPANS``/``TIMED``/``EVENTS``), plus literal ``obs.add`` counter
+  names vs ``COUNTERS`` (membership only — dynamic counter families can't
+  be proven covered by a literal scan)
+* the span names ``repro.obs.trace.JAX_SPANS`` maps JAX's compile events
+  to vs ``JIT_SPANS`` (they have no call site: ``repro.obs`` records them),
+  and the counter names of ``trace.JAX_COUNTERS`` vs ``COUNTERS``
 
 in both directions: an unregistered call-site name is flagged at the call
 site, a catalog row with no remaining call site is flagged at the row.
@@ -38,16 +41,19 @@ _EXEMPT = ("repro/chaos/points.py",)
 _EXEMPT_DIRS = ("repro/obs/", "repro/analysis/")
 
 _OBS_GROUPS = {"span": "SPANS", "timed": "TIMED", "event": "EVENTS"}
-_COUNTER_FUNCS = ("add", "gauge")
+_COUNTER_FUNCS = ("add",)
 
 
 def _norm(path: str) -> str:
     return os.path.abspath(path).replace(os.sep, "/")
 
 
-def _dict_keys(tree: ast.Module, name: str) -> dict[str, int] | None:
+def _dict_keys(
+    tree: ast.Module, name: str, values: bool = False
+) -> dict[str, int] | None:
     """Keys (and linenos) of a module-level dict literal assigned to
-    ``name`` — handles both ``X = {...}`` and ``X: dict[...] = {...}``."""
+    ``name`` — handles both ``X = {...}`` and ``X: dict[...] = {...}``;
+    with ``values``, its string values instead."""
     for node in tree.body:
         target: ast.expr | None = None
         if isinstance(node, ast.Assign) and len(node.targets) == 1:
@@ -62,7 +68,7 @@ def _dict_keys(tree: ast.Module, name: str) -> dict[str, int] | None:
             and isinstance(node.value, ast.Dict)
         ):
             out: dict[str, int] = {}
-            for k in node.value.keys:
+            for k in node.value.values if values else node.value.keys:
                 if isinstance(k, ast.Constant) and isinstance(k.value, str):
                     out[k.value] = k.lineno
             return out
@@ -78,6 +84,7 @@ class CatalogCompleteness(Checker):
             "fault_point": {},
             "SPANS": {},
             "TIMED": {},
+            "JIT_SPANS": {},
             "EVENTS": {},
             "COUNTERS": {},
         }
@@ -123,11 +130,12 @@ class CatalogCompleteness(Checker):
                 )
 
     def _load_catalog(
-        self, project: Project, suffix: tuple[str, ...], var: str
+        self, project: Project, suffix: tuple[str, ...], var: str,
+        values: bool = False,
     ) -> tuple[str, dict[str, int]] | None:
         ctx = project.find(*suffix)
         if ctx is not None:
-            keys = _dict_keys(ctx.tree, var)
+            keys = _dict_keys(ctx.tree, var, values)
             return (ctx.path, keys) if keys is not None else None
         path = project.locate_sibling(*suffix)
         if path is None:
@@ -135,7 +143,7 @@ class CatalogCompleteness(Checker):
         parsed = parse_file(path)
         if isinstance(parsed, Diagnostic):
             return None
-        keys = _dict_keys(parsed.tree, var)
+        keys = _dict_keys(parsed.tree, var, values)
         return (path, keys) if keys is not None else None
 
     def finalize(self, project: Project) -> Iterable[Diagnostic]:
@@ -154,8 +162,17 @@ class CatalogCompleteness(Checker):
         fault = self._load_catalog(project, ("repro", "chaos", "points.py"), "CATALOG")
         obs_catalogs = {
             var: self._load_catalog(project, ("repro", "obs", "catalog.py"), var)
-            for var in ("SPANS", "TIMED", "EVENTS", "COUNTERS")
+            for var in ("SPANS", "TIMED", "JIT_SPANS", "EVENTS", "COUNTERS")
         }
+        # The names repro.obs records JAX's compile events under: their
+        # "call sites" are the values of these mapping literals.
+        for var, group in (("JAX_SPANS", "JIT_SPANS"), ("JAX_COUNTERS", "COUNTERS")):
+            mapping = self._load_catalog(
+                project, ("repro", "obs", "trace.py"), var, values=True
+            )
+            if mapping is not None:
+                for name, line in mapping[1].items():
+                    self._record(group, name, mapping[0], line)
 
         def check_group(
             group: str, catalog: tuple[str, dict[str, int]] | None, registry: str,
@@ -185,7 +202,8 @@ class CatalogCompleteness(Checker):
             "fault_point", fault, "chaos.points.CATALOG", coverage=True
         )
         for var, coverage in (
-            ("SPANS", True), ("TIMED", True), ("EVENTS", True), ("COUNTERS", False),
+            ("SPANS", True), ("TIMED", True), ("JIT_SPANS", True), ("EVENTS", True),
+            ("COUNTERS", False),
         ):
             yield from check_group(
                 var, obs_catalogs[var], f"obs.catalog.{var}", coverage=coverage
@@ -201,7 +219,7 @@ class CatalogCompleteness(Checker):
                         text = f.read()
                 except OSError:
                     text = ""
-                for var in ("SPANS", "TIMED", "EVENTS"):
+                for var in ("SPANS", "TIMED", "JIT_SPANS", "EVENTS"):
                     catalog = obs_catalogs[var]
                     if catalog is None:
                         continue

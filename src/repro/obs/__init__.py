@@ -13,15 +13,13 @@ Usage::
     with obs.enabled() as tracer:
         ...  # any save/restore/hot/serve work
         print(tracer.summary())
-        tracer.export_chrome("trace.json")   # Perfetto-loadable
+    obs.write_chrome_trace("trace.json", tracer)   # Perfetto-loadable
 
-DESIGN.md §9 documents the span taxonomy and sink formats.
+DESIGN.md §9 documents the span taxonomy and the export formats.
 """
 
-from repro.obs.metrics import Metrics, diff_counters
+from repro.obs.metrics import Metrics
 from repro.obs.sinks import (
-    JsonlSink,
-    Recorder,
     chrome_trace,
     format_summary,
     validate_chrome_trace,
@@ -39,16 +37,13 @@ from repro.obs.trace import (
     enable,
     enabled,
     event,
-    gauge,
     span,
     timed,
 )
 
 __all__ = [
-    "JsonlSink",
     "Metrics",
     "NULL_SPAN",
-    "Recorder",
     "Span",
     "Tracer",
     "active",
@@ -56,13 +51,11 @@ __all__ = [
     "attach",
     "chrome_trace",
     "current",
-    "diff_counters",
     "disable",
     "enable",
     "enabled",
     "event",
     "format_summary",
-    "gauge",
     "span",
     "timed",
     "validate_chrome_trace",

@@ -8,7 +8,10 @@ with ``ast`` — never imports it — and checks, at PR time, that
 * every literal ``obs.span("…")`` / ``obs.timed("…")`` / ``obs.event("…")``
   name in the tree appears here (no unregistered instrumentation), and
 * every SPANS/TIMED/EVENTS entry has at least one call site (no stale
-  catalog rows), and every span/timed name is mentioned in DESIGN.md §9.
+  catalog rows), and every span/timed name is mentioned in DESIGN.md §9;
+* ``JIT_SPANS`` equals the names ``repro.obs.trace.JAX_SPANS`` maps JAX's
+  compile events to (those spans have no ``obs.span`` call site), and every
+  name ``trace.JAX_COUNTERS`` bumps is in ``COUNTERS``.
 
 Counters are membership-only: dynamic families (listed at the bottom of
 ``COUNTERS``) are emitted through precomputed names, so a literal-string
@@ -21,7 +24,7 @@ uses.
 
 from __future__ import annotations
 
-__all__ = ["SPANS", "TIMED", "EVENTS", "COUNTERS"]
+__all__ = ["SPANS", "TIMED", "JIT_SPANS", "EVENTS", "COUNTERS"]
 
 # obs.span(name) — scoped regions with containment in the exported trace.
 SPANS: dict[str, str] = {
@@ -47,6 +50,9 @@ SPANS: dict[str, str] = {
     "serve.fetch": "PeerFragmentSource.read_fragment: one fetch-ladder walk",
     "serve.publish": "PublicationRegistry.publish: store + deliver to subscribers",
     "serve.sync": "fleet reader syncing one publication into its engine",
+    "train.batch": "Trainer.run, the step's batch built on the host",
+    "train.dispatch": "Trainer.run, the jitted step called (returns at dispatch)",
+    "train.wait": "Trainer.run, block_until_ready on the step's outputs",
 }
 
 # obs.timed(name) — always-measuring stopwatches at operation granularity.
@@ -62,6 +68,14 @@ TIMED: dict[str, str] = {
     "serve.decode": "serving benchmark decode step",
     "serve.prefill": "serving benchmark prefill step",
     "train.step": "one training step (forward+backward+update)",
+}
+
+# JAX compile-path events recorded as spans by repro.obs itself
+# (trace.JAX_SPANS), on the thread that jits, under its current span.
+JIT_SPANS: dict[str, str] = {
+    "jit.compile": "XLA compile of a lowered module, or its persistent-cache load",
+    "jit.lower": "jaxpr lowered to an MLIR module",
+    "jit.trace": "Python function traced to a jaxpr",
 }
 
 # obs.event(name) — instantaneous markers.
@@ -104,6 +118,8 @@ COUNTERS: dict[str, str] = {
     "hot.mirrored_bytes": "bytes mirrored to replica ranks",
     "hot.resident_bytes": "bytes resident in the hot ring",
     "hot.stored_bytes": "bytes stored per capture",
+    "jit.cache_hits": "persistent compilation cache hits (trace.JAX_COUNTERS)",
+    "jit.cache_misses": "persistent compilation cache misses (trace.JAX_COUNTERS)",
     "restore.arrays": "arrays materialized by restore",
     "restore.bytes_read": "bytes read by restore",
     "restore.count": "restore() calls",

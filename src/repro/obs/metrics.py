@@ -1,4 +1,4 @@
-"""Thread-safe counters and gauges.
+"""Thread-safe counters.
 
 One flat namespace of dotted metric names (``save.bytes_written``,
 ``engine.handle.hit``, ``serve.fetch.peer``).  Everything funnels through
@@ -8,48 +8,26 @@ increment would make the "metrics match the stats dataclasses exactly"
 contract flaky.  The lock is uncontended in practice (updates are
 nanoseconds apart from milliseconds of I/O).
 
-Counters only ever add; gauges keep their latest value.  Snapshots are
-plain dicts so sinks and tests can diff them (capture before, capture
-after, subtract).
+Counters only ever add.  Snapshots are plain dicts so sinks and tests can
+diff them (capture before, capture after, subtract).
 """
 
 from __future__ import annotations
 
 import threading
 
-__all__ = ["Metrics", "diff_counters"]
+__all__ = ["Metrics"]
 
 
 class Metrics:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._counters: dict[str, float] = {}  #: guarded by self._lock
-        self._gauges: dict[str, float] = {}  #: guarded by self._lock
 
     def add(self, name: str, value: float = 1) -> None:
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + value
 
-    def set_gauge(self, name: str, value: float) -> None:
-        with self._lock:
-            self._gauges[name] = value
-
     def counters(self) -> dict[str, float]:
         with self._lock:
             return dict(self._counters)
-
-    def gauges(self) -> dict[str, float]:
-        with self._lock:
-            return dict(self._gauges)
-
-
-def diff_counters(
-    after: dict[str, float], before: dict[str, float]
-) -> dict[str, float]:
-    """Counter deltas between two snapshots (zero-delta keys dropped)."""
-    out: dict[str, float] = {}
-    for k, v in after.items():
-        d = v - before.get(k, 0)
-        if d:
-            out[k] = d
-    return out

@@ -1,20 +1,14 @@
-"""Trace sinks: in-memory recorder, JSONL stream, Chrome trace export.
+"""Exports of a tracer's records: Chrome trace and the summary table.
 
-All sinks consume the plain-dict records produced by
+Both consume the plain-dict records produced by
 :class:`repro.obs.trace.Tracer` (``kind``: ``span`` or ``event``) — no
-sink imports the tracer, so the dependency points one way.
+export imports the tracer, so the dependency points one way.
 
-Formats
--------
-* **Recorder** — appends records to lists; the test sink.
-* **JsonlSink** — one JSON object per line, written as each span
-  *finishes* (a crash leaves a partial timeline on disk).  The line form
-  is exactly the record dict.
-* **Chrome trace** — ``{"traceEvents": [...]}`` loadable by Perfetto /
-  ``chrome://tracing``: ``ph:"X"`` complete events for spans (``ts`` /
-  ``dur`` in microseconds on one monotonic timebase), ``ph:"i"`` instant
-  events, ``ph:"M"`` thread-name metadata, and the final counter
-  snapshot under ``otherData.counters``.
+The Chrome trace is ``{"traceEvents": [...]}``, loadable by Perfetto /
+``chrome://tracing``: ``ph:"X"`` complete events for spans (``ts`` /
+``dur`` in microseconds on one monotonic timebase), ``ph:"i"`` instant
+events, ``ph:"M"`` thread-name metadata, and the final counter snapshot
+under ``otherData.counters``.
 """
 
 from __future__ import annotations
@@ -24,52 +18,11 @@ from pathlib import Path
 from typing import Any
 
 __all__ = [
-    "JsonlSink",
-    "Recorder",
     "chrome_trace",
     "format_summary",
     "validate_chrome_trace",
     "write_chrome_trace",
 ]
-
-
-class Recorder:
-    """In-memory streaming sink (tests; chaos timelines)."""
-
-    def __init__(self) -> None:
-        self.records: list[dict[str, Any]] = []
-
-    def on_record(self, rec: dict[str, Any]) -> None:
-        self.records.append(rec)
-
-    def spans(self) -> list[dict[str, Any]]:
-        return [r for r in self.records if r["kind"] == "span"]
-
-    def events(self) -> list[dict[str, Any]]:
-        return [r for r in self.records if r["kind"] == "event"]
-
-
-class JsonlSink:
-    """Append-per-record JSONL writer.
-
-    Opened lazily on the first record so constructing a tracer with a
-    configured-but-unused sink touches no filesystem (the benchmark file
-    census counts every byte under its tmp roots)."""
-
-    def __init__(self, path: str | Path):
-        self.path = Path(path)
-        self._fh = None
-
-    def on_record(self, rec: dict[str, Any]) -> None:
-        if self._fh is None:
-            self.path.parent.mkdir(parents=True, exist_ok=True)
-            self._fh = open(self.path, "w", encoding="utf-8")
-        self._fh.write(json.dumps(rec, sort_keys=True) + "\n")
-
-    def close(self) -> None:
-        if self._fh is not None:
-            self._fh.close()
-            self._fh = None
 
 
 def chrome_trace(tracer) -> dict[str, Any]:
@@ -122,7 +75,6 @@ def chrome_trace(tracer) -> dict[str, Any]:
         "otherData": {
             "schema": "repro-trace/v1",
             "counters": tracer.counters(),
-            "gauges": tracer.metrics.gauges(),
         },
     }
 

@@ -20,8 +20,18 @@ handoff is simply a root span, which is loud in the exported timeline.
 Timestamps are ``time.perf_counter_ns()`` relative to the tracer's epoch:
 one monotonic timebase for every thread, so exported ``ts``/``dur`` pairs
 are mutually consistent (children lie inside their parents).  Wall-clock
-never enters the trace; the injectable ``repro.core.clock`` stays a
-commit/GC-policy concern (see its docstring).
+enters only through JAX's compile events, converted once by the pair of
+clock readings the tracer takes when it is made; the injectable
+``repro.core.clock`` stays a commit/GC-policy concern (see its docstring).
+
+The profiler's clock: while a tracer is enabled every real span also opens
+a ``jax.profiler.TraceAnnotation`` of its name on its own thread, so a
+``jax.profiler`` trace holds the program's spans beside the device's ops.
+JAX's jit compile path (trace, lower, compile or cache load) is recorded
+as ``jit.*`` spans on the calling thread, parented to its current span,
+and its persistent-cache hits and misses as ``jit.cache_*`` counters.  JAX
+is imported, and its listeners registered, at the first :func:`enable`;
+never at import, and never while tracing is off.
 """
 
 from __future__ import annotations
@@ -29,7 +39,7 @@ from __future__ import annotations
 import itertools
 import threading
 import time
-from typing import Any, Iterator
+from typing import Any
 
 __all__ = [
     "Span",
@@ -70,6 +80,7 @@ class Span:
         "t0_ns",
         "t1_ns",
         "_tracer",
+        "_mirror",
     )
 
     def __init__(
@@ -88,6 +99,7 @@ class Span:
         self.thread_name = ""
         self.t0_ns = 0
         self.t1_ns = 0
+        self._mirror = None
 
     def set(self, **attrs: Any) -> "Span":
         """Merge attributes into the span (chainable)."""
@@ -104,11 +116,15 @@ class Span:
         self.tid = t.ident or 0
         self.thread_name = t.name
         _stack().append(self)
+        self._mirror = _profiler_annotation(self.name)
+        self._mirror.__enter__()
         self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.t1_ns = time.perf_counter_ns()
+        self._mirror.__exit__(None, None, None)
+        self._mirror = None
         st = _stack()
         if st and st[-1] is self:
             st.pop()
@@ -201,24 +217,21 @@ class _Attach:
 
 
 class Tracer:
-    """Collects finished spans, instant events and counters.
+    """Collects finished spans, instant events and counters in memory
+    (``span_records()``, ``event_records()``, ``counters()``)."""
 
-    Always records in memory (`span_records()` — the test recorder);
-    extra streaming sinks (e.g. :class:`repro.obs.sinks.JsonlSink`)
-    receive each record as it finishes, so a crashed process still leaves
-    a partial timeline on disk.
-    """
-
-    def __init__(self, sinks: list | None = None):
+    def __init__(self):
         from repro.obs.metrics import Metrics  # leaf module, no cycle
 
         self.metrics = Metrics()
+        # One pair of readings maps JAX's time.time() seconds onto the
+        # epoch's timebase (``record_span``).
+        self.wall_ns0 = time.time_ns()
         self.epoch_ns = time.perf_counter_ns()
         self._ids = itertools.count(1)
         self._lock = threading.Lock()
         self._spans: list[dict[str, Any]] = []  #: guarded by self._lock
         self._events: list[dict[str, Any]] = []  #: guarded by self._lock
-        self._sinks = list(sinks or [])
 
     # -- producers ---------------------------------------------------------
     def span(self, name: str, parent: Span | None = None, **attrs: Any) -> Span:
@@ -228,6 +241,18 @@ class Tracer:
             st = _stack()
             pid = st[-1].span_id if st else None
         return Span(self, name, pid, attrs)
+
+    def record_span(self, name: str, start_s: float, end_s: float, **attrs: Any) -> None:
+        """Record a span that ran between two ``time.time()`` readings on
+        this thread, as a child of its current span (spans known only after
+        the fact, such as JAX's compile events; not mirrored)."""
+        sp = self.span(name, **attrs)
+        t = threading.current_thread()
+        sp.tid, sp.thread_name = t.ident or 0, t.name
+        base = self.epoch_ns - self.wall_ns0
+        sp.t0_ns = base + round(start_s * 1e9)
+        sp.t1_ns = base + round(end_s * 1e9)
+        self._finish(sp)
 
     def emit_event(self, name: str, attrs: dict[str, Any]) -> None:
         t = threading.current_thread()
@@ -243,15 +268,11 @@ class Tracer:
         }
         with self._lock:
             self._events.append(rec)
-            for s in self._sinks:
-                s.on_record(rec)
 
     def _finish(self, span: Span) -> None:
         rec = span.record(self.epoch_ns)
         with self._lock:
             self._spans.append(rec)
-            for s in self._sinks:
-                s.on_record(rec)
 
     # -- consumers ---------------------------------------------------------
     def span_records(self) -> list[dict[str, Any]]:
@@ -276,22 +297,6 @@ class Tracer:
 
         return format_summary(self.span_records(), self.counters())
 
-    def chrome_trace(self) -> dict[str, Any]:
-        from repro.obs.sinks import chrome_trace
-
-        return chrome_trace(self)
-
-    def export_chrome(self, path) -> None:
-        from repro.obs.sinks import write_chrome_trace
-
-        write_chrome_trace(path, self)
-
-    def close(self) -> None:
-        for s in self._sinks:
-            close = getattr(s, "close", None)
-            if close is not None:
-                close()
-
 
 # ---------------------------------------------------------------------------
 # The process-wide gate.  Same discipline as chaos/points.py: one global,
@@ -299,6 +304,54 @@ class Tracer:
 
 _tracer: Tracer | None = None
 _activation_lock = threading.Lock()
+#: ``jax.profiler.TraceAnnotation``, bound by the first :func:`enable`.
+_profiler_annotation = None
+
+# JAX's compile-path events (``jax.monitoring``) and what each records.
+# ``repro.analysis`` checks these literals against ``obs.catalog.JIT_SPANS``
+# and ``obs.catalog.COUNTERS``.
+JAX_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    # wraps compile_or_get_cached: the persistent-cache lookup and load too
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
+JAX_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": "jit.cache_hits",
+    "/jax/compilation_cache/cache_misses": "jit.cache_misses",
+}
+
+
+def _on_jax_span(event: str, start_s: float, end_s: float, **kw: Any) -> None:
+    t = _tracer
+    if t is None:
+        return
+    name = JAX_SPANS.get(event)
+    if name is not None:
+        t.record_span(name, start_s, end_s, fun_name=kw.get("fun_name"))
+
+
+def _on_jax_event(event: str, **kw: Any) -> None:
+    t = _tracer
+    if t is None:
+        return
+    name = JAX_COUNTERS.get(event)
+    if name is not None:
+        t.metrics.add(name, 1)
+
+
+def _hook_jax() -> None:
+    """Bind the profiler annotation and register the compile-event
+    listeners, once per process.  Caller holds ``_activation_lock``."""
+    global _profiler_annotation
+    if _profiler_annotation is not None:
+        return
+    import jax.monitoring
+    import jax.profiler
+
+    jax.monitoring.register_event_time_span_listener(_on_jax_span)
+    jax.monitoring.register_event_listener(_on_jax_event)
+    _profiler_annotation = jax.profiler.TraceAnnotation
 
 
 def enable(tracer: Tracer | None = None) -> Tracer:
@@ -310,6 +363,7 @@ def enable(tracer: Tracer | None = None) -> Tracer:
                 "a tracer is already enabled; tracing is process-exclusive "
                 "(disable the other one first)"
             )
+        _hook_jax()
         _tracer = tracer if tracer is not None else Tracer()
         return _tracer
 
@@ -378,13 +432,6 @@ def add(name: str, value: float = 1, /) -> None:
         t.metrics.add(name, value)
 
 
-def gauge(name: str, value: float, /) -> None:
-    """Set a gauge to its latest value.  No-op when disabled."""
-    t = _tracer
-    if t is not None:
-        t.metrics.set_gauge(name, value)
-
-
 def event(name: str, /, **attrs: Any) -> None:
     """Record an instant event (fault-point hit, invariant check, tier
     fallback).  No-op when disabled."""
@@ -410,11 +457,3 @@ def attach(parent: Span | None):
     if _tracer is None or parent is None:
         return NULL_SPAN
     return _Attach(parent)
-
-
-def iter_children(records: list[dict[str, Any]], span_id: int) -> Iterator[dict]:
-    """Direct children of ``span_id`` among span records (shared helper
-    for summaries and coverage checks)."""
-    for r in records:
-        if r.get("parent_id") == span_id and r.get("kind") == "span":
-            yield r

@@ -198,10 +198,14 @@ class Trainer:
         with self.jmesh:
             for step in range(start_step, start_step + num_steps):
                 with obs.timed("train.step", step=step + 1) as sw:
-                    state, metrics = self.step_fn(state, self.batch(step))
+                    with obs.span("train.batch"):
+                        batch = self.batch(step)
+                    with obs.span("train.dispatch"):
+                        state, metrics = self.step_fn(state, batch)
                     # jit returns at dispatch: the step ends when the
                     # device has produced its outputs.
-                    jax.block_until_ready((state, metrics))
+                    with obs.span("train.wait"):
+                        jax.block_until_ready((state, metrics))
                 rec = {
                     "step": step + 1,
                     "loss": float(metrics["loss"]),

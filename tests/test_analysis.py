@@ -161,8 +161,10 @@ def test_decode_point_allows_read_layer_and_suppression(tmp_path):
 # catalog
 
 
-def _mini_tree(tmp_path, foo_source):
-    """A minimal repro-shaped tree: registries + one call-site module."""
+def _mini_tree(tmp_path, foo_source, catalog_extra="", obs_trace=None):
+    """A minimal repro-shaped tree: registries + one call-site module
+    (``catalog_extra`` is appended to ``obs/catalog.py``; ``obs_trace``,
+    when given, is ``obs/trace.py``)."""
     (tmp_path / "repro/chaos").mkdir(parents=True)
     (tmp_path / "repro/obs").mkdir(parents=True)
     (tmp_path / "repro/ckpt").mkdir(parents=True)
@@ -176,8 +178,10 @@ def _mini_tree(tmp_path, foo_source):
         'SPANS: dict[str, str] = {"save.shard": "one shard"}\n'
         "TIMED: dict[str, str] = {}\n"
         "EVENTS: dict[str, str] = {}\n"
-        "COUNTERS: dict[str, str] = {}\n"
+        "COUNTERS: dict[str, str] = {}\n" + catalog_extra
     )
+    if obs_trace is not None:
+        (tmp_path / "repro/obs/trace.py").write_text(obs_trace)
     (tmp_path / "repro/ckpt/saver.py").write_text(
         'from repro.chaos.points import fault_point\n'
         'import repro.obs as obs\n'
@@ -204,6 +208,27 @@ def test_catalog_flags_unregistered_and_stale_names(tmp_path):
     )
     assert any('"gone.point" has no call site left' in m for m in msgs)
     assert len(diags) == 3
+
+
+def test_catalog_checks_jit_spans_against_the_jax_mapping(tmp_path):
+    """The jit.* spans have no obs.span call site: the values of
+    obs/trace.py's JAX_SPANS / JAX_COUNTERS literals stand in for them."""
+    diags = _mini_tree(
+        tmp_path,
+        "x = 1\n",
+        catalog_extra='JIT_SPANS: dict[str, str] = {\n'
+        '    "jit.trace": "traced",\n'
+        '    "jit.stale": "no event maps here",\n'
+        '}\n',
+        obs_trace='JAX_SPANS = {"/jax/a": "jit.trace", "/jax/b": "jit.unlisted"}\n'
+        'JAX_COUNTERS = {"/jax/c": "jit.cache_hits"}\n',
+    )
+    msgs = sorted(d.message for d in diags)
+    assert any('"jit.unlisted" is not in obs.catalog.JIT_SPANS' in m for m in msgs)
+    assert any('JIT_SPANS entry "jit.stale" has no call site left' in m for m in msgs)
+    assert any('"jit.cache_hits" is not in obs.catalog.COUNTERS' in m for m in msgs)
+    assert any('"gone.point" has no call site left' in m for m in msgs)
+    assert len(diags) == 4
 
 
 def test_catalog_requires_literal_names(tmp_path):

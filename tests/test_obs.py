@@ -8,18 +8,26 @@ Tracer involvement); tracing on means every span lands on one monotonic
 timebase with an explicit parent chain that survives thread handoffs.
 """
 
+import glob
 import json
+import os
+import statistics
+import subprocess
+import sys
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
+from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.launch.mesh import make_mesh
 import repro.obs as obs
 import repro.obs.trace as trace_mod
-from repro.configs import ParallelismConfig, get_config, reduced
+from repro.configs import ParallelismConfig, TrainConfig, get_config, reduced
 from repro.core.dist_ckpt import DistCheckpoint
 from repro.core.layout import MeshSpec
 from repro.core.pytree import flatten_with_paths, unflatten_from_paths
@@ -28,6 +36,9 @@ from repro.ckpt.saver import snapshot_state, write_distributed
 from repro.dist.sharding import make_plan, vocab_multiple
 from repro.models import build_model
 from repro.train.optimizer import TrainState, init_state
+from repro.train.trainer import Trainer
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture(autouse=True)
@@ -88,9 +99,45 @@ def test_disabled_never_touches_tracer(monkeypatch):
     )
     with obs.span("x"):
         obs.add("counter.name", 3)
-        obs.gauge("gauge.name", 1.5)
         obs.event("event.name", detail="ignored")
     assert calls == []
+
+
+DISABLED_CHILD = """
+import sys
+import repro.obs as obs
+with obs.span("a"), obs.timed("b"):
+    obs.add("c", 1)
+    obs.event("d")
+assert "jax" not in sys.modules, "repro.obs imported jax with tracing off"
+import jax, jax.numpy as jnp, jax.profiler
+from jax._src import monitoring
+import repro.obs.trace as trace_mod
+
+def touched(*a, **k):
+    raise AssertionError("jax.profiler touched with tracing off")
+
+jax.profiler.TraceAnnotation = touched
+listeners = lambda: (len(monitoring.get_event_time_span_listeners()),
+                     len(monitoring.get_event_listeners()))
+before = listeners()
+with obs.span("a"), obs.timed("b"):
+    jax.jit(lambda x: x + 1)(jnp.ones(3)).block_until_ready()
+assert listeners() == before, "a jax.monitoring listener was registered"
+assert trace_mod._profiler_annotation is None
+print("clean")
+"""
+
+
+def test_disabled_imports_no_jax_and_registers_nothing():
+    """Tracing off: ``repro.obs`` imports no JAX, creates no profiler
+    annotation and registers no ``jax.monitoring`` listener (a fresh
+    process: a test worker may have enabled a tracer already)."""
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", DISABLED_CHILD], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "clean"
 
 
 def test_disabled_timed_still_measures():
@@ -185,6 +232,160 @@ def test_async_saver_job_parented_to_submit(model_setup, tmp_path):
     assert job["tid"] != submit["tid"]  # really ran on the writer thread
     (save_rec,) = [r for r in recs if r["name"] == "ckpt.save"]
     assert save_rec["parent_id"] == job["span_id"]
+
+
+# ---------------------------------------------------------------------------
+# The profiler's clock and the jit compile path
+
+
+def _host_events(profile_dir, prefix):
+    """Host events named ``prefix…`` in a ``jax.profiler`` trace, per
+    thread line: {line index: [(name, start_ns, duration_ns), ...]}."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(Path(profile_dir) / "**" / "*.xplane.pb"), recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                evs = [(e.name, e.start_ns, e.duration_ns) for e in line.events
+                       if e.name.startswith(prefix)]
+                if evs:
+                    out[i] = evs
+    return out
+
+
+def test_spans_mirrored_on_the_profiler_clock(tmp_path):
+    """Each real span is also a profiler host event of its name on its own
+    thread; mapped by the median offset of the matched pairs, every start
+    lies within 100 us of the obs record and every duration within 50 us."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.enabled() as tracer:
+            for _ in range(4):
+                with obs.span("mirror.outer"):
+                    time.sleep(0.002)
+                    with obs.timed("mirror.inner"):
+                        time.sleep(0.001)
+
+            def worker():
+                with obs.span("mirror.worker"):
+                    time.sleep(0.001)
+
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join(timeout=60)
+            assert not t.is_alive()
+    finally:
+        jax.profiler.stop_trace()
+    lines = _host_events(tmp_path, "mirror.")
+    recs = tracer.span_records()
+    pairs = []
+    for thread in {r["thread"] for r in recs}:
+        mine = [r for r in recs if r["thread"] == thread]
+        (line,) = [evs for evs in lines.values()
+                   if {n for n, _, _ in evs} == {r["name"] for r in mine}]
+        for name in {r["name"] for r in mine}:
+            obs_side = sorted((r["ts_us"] * 1e3, r["dur_us"] * 1e3) for r in mine
+                              if r["name"] == name)
+            prof_side = sorted((s, d) for n, s, d in line if n == name)
+            assert len(obs_side) == len(prof_side)
+            pairs += list(zip(obs_side, prof_side))
+    assert len(pairs) == 9
+    offset = statistics.median(p[0] - o[0] for o, p in pairs)
+    for (o_start, o_dur), (p_start, p_dur) in pairs:
+        assert abs(p_start - offset - o_start) <= 100e3
+        assert abs(p_dur - o_dur) <= 50e3
+
+
+def scaled_sin(x):
+    return jnp.sin(x) * 3.0 + 1.0
+
+
+def test_jit_compile_path_recorded_as_spans():
+    """A fresh jitted function traced, lowered and compiled under a tracer
+    leaves ``jit.trace``/``jit.lower``/``jit.compile`` inside the enclosing
+    span on the same thread; its cached second call leaves none."""
+    f = jax.jit(scaled_sin)
+    x = jnp.arange(5.0)
+    with obs.enabled() as tracer:
+        with obs.span("cold"):
+            f(x).block_until_ready()
+        with obs.span("warm"):
+            f(x).block_until_ready()
+    recs = tracer.span_records()
+    by = {r["name"]: r for r in recs if r["name"] in ("cold", "warm")}
+    jit = [r for r in recs if r["name"].startswith("jit.")]
+    assert {r["name"] for r in jit} == {"jit.trace", "jit.lower", "jit.compile"}
+    cold = by["cold"]
+    for r in jit:
+        assert r["parent_id"] == cold["span_id"] and r["tid"] == cold["tid"]
+        assert r["ts_us"] >= cold["ts_us"] - 1
+        assert r["ts_us"] + r["dur_us"] <= cold["ts_us"] + cold["dur_us"] + 1
+    assert any(r["attrs"]["fun_name"] == "scaled_sin" for r in jit
+               if r["name"] == "jit.trace")
+
+
+CACHE_CHILD = """
+import json
+import jax, jax.numpy as jnp
+import repro.obs as obs
+with obs.enabled() as tracer:
+    jax.jit(lambda x: jnp.cos(x) * 5.0)(jnp.arange(7.0)).block_until_ready()
+print(json.dumps(tracer.counters()))
+"""
+
+
+def test_jit_cache_hits_and_misses_counted(tmp_path):
+    """The persistent compilation cache's misses (first process) and hits
+    (second process, same cache) become ``jit.cache_*`` counters."""
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    counts = []
+    for _ in range(2):
+        out = subprocess.run([sys.executable, "-c", CACHE_CHILD], capture_output=True,
+                             text=True, env=env, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        counts.append(json.loads(out.stdout.strip().splitlines()[-1]))
+    assert counts[0].get("jit.cache_misses", 0) >= 1
+    assert counts[0].get("jit.cache_hits", 0) == 0
+    assert counts[1].get("jit.cache_hits", 0) >= 1
+
+
+def test_trainer_step_phases_in_order():
+    """Each ``train.step`` holds ``train.batch``, ``train.dispatch`` and
+    ``train.wait`` in that order; the first (cold) step's compile spans
+    nest in its dispatch, and the warm step compiles nothing."""
+    cfg = reduced(get_config("smollm-360m"))
+    trainer = Trainer.create(
+        cfg, ParallelismConfig(), TrainConfig(warmup_steps=2, total_steps=10),
+        make_mesh((1, 1), ("data", "model")), batch_size=2, seq_len=16,
+    )
+    state = trainer.init_state()
+    with obs.enabled() as tracer:
+        trainer.run(state, 0, 2)
+    recs = tracer.span_records()
+    by_id = {r["span_id"]: r for r in recs}
+    steps = sorted((r for r in recs if r["name"] == "train.step"),
+                   key=lambda r: r["ts_us"])
+    assert [r["attrs"]["step"] for r in steps] == [1, 2]
+    for step in steps:
+        kids = sorted((r for r in recs if r["parent_id"] == step["span_id"]),
+                      key=lambda r: r["ts_us"])
+        assert [r["name"] for r in kids] == ["train.batch", "train.dispatch", "train.wait"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["ts_us"] + a["dur_us"] <= b["ts_us"]
+        assert step["ts_us"] <= kids[0]["ts_us"]
+        assert kids[-1]["ts_us"] + kids[-1]["dur_us"] <= step["ts_us"] + step["dur_us"]
+    jit = [r for r in recs if r["name"].startswith("jit.")]
+    assert jit, "the cold step compiled nothing"
+    for r in jit:
+        parent = by_id[r["parent_id"]]
+        assert parent["name"] in ("train.batch", "train.dispatch")
+        assert by_id[parent["parent_id"]] is steps[0]
+    assert any(by_id[r["parent_id"]]["name"] == "train.dispatch" for r in jit)
 
 
 # ---------------------------------------------------------------------------
