@@ -25,14 +25,18 @@ All file I/O routes through a :class:`~repro.core.engine.CheckpointEngine`:
 fragment lookups hit the engine's sorted interval index (built once per
 ``(checkpoint, param, kind)``), shard/atom files are opened once through its
 handle cache, and ``_build_state`` prefetches every device region
-concurrently over the engine's worker pool.  ``CheckpointEngine(workers=1)``
-degrades to the exact serial order, byte-identical by construction.
+concurrently over the engine's worker pool.  A region that one raw shard
+file on disk holds whole skips the handle cache: its payload is read
+straight into the region's staging buffer in byte ranges, jobs of the same
+pool (``_prefetch``).  ``CheckpointEngine(workers=1)`` degrades to the exact
+serial order, byte-identical by construction.
 """
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Mapping
+from typing import Callable, Mapping
 
 import jax
 import numpy as np
@@ -41,11 +45,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import repro.obs as obs
 from repro.core.atoms import UcpCheckpoint
 from repro.core.convert import assemble_atom
+from repro.core.dist_ckpt import DistCheckpoint
 from repro.core.engine import CheckpointEngine, default_engine
 from repro.core.ops import clip_region_to_logical, read_runtime_region
 from repro.core.patterns import ParamTransform, StateKind, TransformClass
 from repro.core.pytree import unflatten_from_paths
-from repro.core.tensor_io import resolve_dtype
+from repro.core.tensor_io import READ_RANGE_BYTES, read_into, resolve_dtype
 from repro.dist.sharding import ShardingPlan
 from repro.train.optimizer import TrainState
 
@@ -156,6 +161,7 @@ def _build_trees(
     engine: CheckpointEngine | None = None,
     *,
     names: set[str] | None = None,
+    locate: Callable | None = None,
 ) -> dict[str, dict[str, jax.Array]]:
     """Build the requested state trees as flat ``{field: {name: array}}``.
 
@@ -164,6 +170,8 @@ def _build_trees(
     ``fields`` selects which state kinds to build (the full ladder for a
     training resume, params-only for a serving reader) and ``names``
     restricts to a parameter subset (delta-subscription in-place updates).
+    ``locate`` (see :func:`_whole_fragment_locator`) names the regions that
+    one raw shard file serves whole; those skip ``reader``.
     """
     engine = engine or default_engine()
     pspecs = plan.state_pspecs()
@@ -192,7 +200,7 @@ def _build_trees(
                     seen.add(key)
                     jobs.append((name, spec.states[kind].dtype, canon))
         with obs.span("restore.prefetch", field=field, regions=len(jobs)):
-            results = engine.map(lambda j: reader(j[0], kind, j[2], j[1]), jobs)
+            results = _prefetch(reader, locate, kind, jobs, engine)
         table = {
             (n, tuple((r.start, r.stop) for r in canon)): arr
             for (n, _, canon), arr in zip(jobs, results)
@@ -228,6 +236,74 @@ def _build_trees(
     return trees
 
 
+def _prefetch(reader, locate, kind: StateKind, jobs, engine: CheckpointEngine) -> list:
+    """Read every ``(name, dtype, region)`` job of one state kind, in order.
+
+    A region that ``locate`` finds whole in one raw shard file is read
+    straight into its destination buffer as fixed-size byte ranges (no
+    decoded copy in the handle cache, no second copy into staging); every
+    other region is one ``reader`` call.  Range jobs and reader jobs share
+    one ``engine.map`` and are all enumerated here, up front: a job never
+    submits to the pool and waits on it, which would deadlock once every
+    worker is busy.
+    """
+    out: list = [None] * len(jobs)
+    tasks: list[Callable[[], np.ndarray | None]] = []
+    slots: list[int | None] = []  # the job a task's result fills, if any
+    for i, (name, dtype, region) in enumerate(jobs):
+        found = locate(name, kind, region, dtype) if locate is not None else None
+        if found is None:
+            tasks.append(functools.partial(reader, name, kind, region, dtype))
+            slots.append(i)
+            continue
+        path, offset = found
+        shape = tuple(r.stop - r.start for r in region)
+        arr = engine.alloc(shape, resolve_dtype(dtype), zero=False)
+        out[i] = arr
+        buf = memoryview(arr.reshape(-1).view(np.uint8))
+        for lo in range(0, arr.nbytes, READ_RANGE_BYTES):
+            tasks.append(functools.partial(
+                read_into, path, offset + lo, buf[lo:lo + READ_RANGE_BYTES]
+            ))
+            slots.append(None)
+        obs.add("restore.whole_fragment_reads")
+        obs.add("restore.whole_fragment_bytes", arr.nbytes)
+    for i, arr in zip(slots, engine.map(lambda task: task(), tasks)):
+        if i is not None:
+            out[i] = arr
+    return out
+
+
+def _whole_fragment_locator(
+    source, engine: CheckpointEngine, plan: ShardingPlan | None = None,
+    transforms: Mapping[str, ParamTransform] | None = None,
+):
+    """``locate(name, kind, region, dtype) -> (path, offset) | None`` for a
+    disk checkpoint (None for any other source): where one raw shard file
+    holds exactly the bytes the region's reader would serve.
+
+    With ``transforms`` (the streamed reshard), only the regions the
+    stream reader serves as one unclipped fragment read qualify: never a
+    ``CONSOLIDATE`` parameter, never a region reaching into padding.
+    """
+    if not isinstance(source, DistCheckpoint):
+        return None
+
+    def locate(name, kind, region, dtype):
+        if transforms is not None:
+            if transforms[name].cls is TransformClass.CONSOLIDATE:
+                return None
+            clipped = clip_region_to_logical(
+                region, plan.param_specs[name].logical_shape
+            )
+            if clipped is None or not clipped[2]:
+                return None
+            region = clipped[0]
+        return source.whole_fragment(name, kind, region, dtype, engine=engine)
+
+    return locate
+
+
 def _build_state(
     reader,  # (name, kind, region, dtype) -> np.ndarray
     plan: ShardingPlan,
@@ -235,10 +311,11 @@ def _build_state(
     step: int,
     stats: RestoreStats | None = None,
     engine: CheckpointEngine | None = None,
+    locate: Callable | None = None,
 ) -> TrainState:
     import jax.numpy as jnp
 
-    trees = _build_trees(reader, plan, jmesh, _FIELDS, stats, engine)
+    trees = _build_trees(reader, plan, jmesh, _FIELDS, stats, engine, locate=locate)
     return TrainState(
         params=unflatten_from_paths(trees["params"]),
         exp_avg=unflatten_from_paths(trees["exp_avg"]),
@@ -270,7 +347,10 @@ def state_from_source(
     or in-memory hot snapshot) via indexed region reads."""
     engine = engine or default_engine()
     reader = _source_reader(source, engine)
-    return _build_state(reader, plan, jmesh, int(source.manifest.step), stats, engine)
+    return _build_state(
+        reader, plan, jmesh, int(source.manifest.step), stats, engine,
+        _whole_fragment_locator(source, engine),
+    )
 
 
 # Historical name, kept for disk-checkpoint call sites.
@@ -309,7 +389,10 @@ def state_from_stream(
     """
     engine = engine or default_engine()
     reader = _stream_reader(source, plan, transforms, engine)
-    return _build_state(reader, plan, jmesh, int(source.manifest.step), stats, engine)
+    return _build_state(
+        reader, plan, jmesh, int(source.manifest.step), stats, engine,
+        _whole_fragment_locator(source, engine, plan, transforms),
+    )
 
 
 def _stream_reader(
@@ -396,7 +479,7 @@ def build_param_arrays(
     )
     trees = _build_trees(
         reader, plan, jmesh, (("params", StateKind.FP32),), stats, engine,
-        names=names,
+        names=names, locate=_whole_fragment_locator(source, engine, plan, transforms),
     )
     return trees["params"]
 

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
 from pathlib import Path
 from typing import Any, Iterator, Mapping
@@ -46,7 +47,14 @@ from repro.chaos.points import fault_point
 from . import clock, codec
 from .layout import MeshSpec, ShardLayout
 from .patterns import ParamSpec, StateKind
-from .tensor_io import content_digest, dtype_name, load_tensor, save_tensor
+from .tensor_io import (
+    content_digest,
+    dtype_name,
+    load_tensor,
+    npy_payload_offset,
+    resolve_dtype,
+    save_tensor,
+)
 
 __all__ = [
     "DistManifest",
@@ -448,6 +456,50 @@ class DistCheckpoint:
         if engine is not None:
             return engine.read_shard(self, rank, name, kind)
         return self.read_shard(rank, name, kind)
+
+    def whole_fragment(
+        self, name: str, kind: StateKind, region: tuple[slice, ...], dtype, *, engine
+    ) -> tuple[Path, int] | None:
+        """``(path, payload offset)`` when one raw shard file holds exactly
+        ``region`` (unit-step runtime slices) in ``dtype``, byte for byte,
+        so its payload can be read straight into the region's buffer; else
+        None (a partial overlap or a union of files, a coded shard, another
+        dtype or a header that is not the manifest's C-order shape).
+
+        The region's fragment hits must all come from one rank's file, each
+        at the same position in the shard as in the region, together
+        covering both: a fused dimension's sub-fragments (one entry per
+        part) then tile the file in place just as one whole entry does."""
+        spec = self.manifest.params[name]
+        if resolve_dtype(spec.states[kind].dtype) != resolve_dtype(dtype):
+            return None
+        idx = engine.index_for(self, name, kind)
+        hits = idx.overlapping(region)
+        local = tuple(idx.layout.local_shape)
+        if (
+            not hits
+            or tuple(r.stop - r.start for r in region) != local
+            or len({rank for rank, _, _ in hits}) != 1
+        ):
+            return None
+        covered = 0
+        for _, e, ovs in hits:
+            if any(
+                s0 + (lo - a0) != lo - r.start
+                for (a0, _), (s0, _), (lo, _), r in zip(
+                    e.atom_slice, e.shard_slice, ovs, region
+                )
+            ):
+                return None
+            covered += math.prod(hi - lo for lo, hi in ovs)
+        if covered != math.prod(local):  # fragments are disjoint: a sum
+            return None
+        rank = hits[0][0]
+        if self.manifest.codec_tag(shard_digest_key(rank, name, kind)) != "raw":
+            return None
+        path = self.shard_path(rank, name, kind)
+        offset = npy_payload_offset(path, local, dtype)
+        return None if offset is None else (path, offset)
 
     def iter_param_fragments(
         self, name: str, kind: StateKind, *, engine=None

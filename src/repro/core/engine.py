@@ -13,7 +13,12 @@ reconfiguration cost) depend on operationally:
   ``layout_for`` per call.
 * :class:`HandleCache` — a bounded, thread-safe LRU of open mmap handles
   keyed by file path.  A restore of N parameters × R device regions opens
-  each shard/atom file once, not once per region.
+  each shard/atom file once, not once per region.  A region that one raw
+  shard file holds whole bypasses it: ``repro.ckpt.restore`` reads that
+  file's payload straight into the region's arena buffer, in byte ranges
+  enumerated up front as jobs of the same :meth:`CheckpointEngine.map`
+  (never submitted from inside a job, which would deadlock a full pool);
+  caching a file read exactly once per restore would only pin memory.
 * a bounded worker pool (:meth:`CheckpointEngine.map`) — shard writes and
   region reads are mmap/memcpy/fsync work that releases the GIL, so both
   directions fan out over threads; ``workers=1`` degrades to the exact
@@ -484,12 +489,15 @@ class CheckpointEngine:
         pre-engine code path, kept so the parallel engine stays
         benchmarkable against it.  ``workers>1`` enables the engine
         machinery: ``mmap_handles=False`` materializes each shard/atom file
-        into the handle cache on first touch (one sequential read per file,
-        after which every region copy runs at memory speed and
-        parallelizes; lazy mmap views instead re-fault pages through the
-        filesystem on every access, and those faults serialize across
-        threads), and ``use_arena=True`` recycles staging buffers (see
-        :class:`BufferArena`).  Both flags can also be forced explicitly."""
+        into the handle cache on first touch, so the several regions cut
+        from one file copy out of memory (lazy mmap views instead re-fault
+        pages through the filesystem on every access, and those faults
+        serialize across threads), and ``use_arena=True`` recycles staging
+        buffers (see :class:`BufferArena`).  Both flags can also be forced
+        explicitly.  A region one raw shard file serves whole (every region
+        of a same-layout DIRECT resume) is read straight into its staging
+        buffer instead and never enters the handle cache: materializing
+        it there would be a second full copy of each byte."""
         self.workers = default_workers() if workers is None else int(workers)
         if self.workers < 1:
             raise ValueError(f"workers must be >= 1, got {workers}")

@@ -17,6 +17,7 @@ from __future__ import annotations
 import hashlib
 import os
 import zlib
+from mmap import PAGESIZE
 from pathlib import Path
 
 import ml_dtypes
@@ -36,9 +37,15 @@ __all__ = [
     "dtype_name",
     "save_tensor",
     "load_tensor",
+    "npy_payload_offset",
+    "read_into",
+    "READ_RANGE_BYTES",
     "open_memmap",
     "fsync_path",
 ]
+
+#: Size of one byte-range job of a whole-file read (see :func:`read_into`).
+READ_RANGE_BYTES = 32 << 20
 
 
 class IntegrityError(ValueError):
@@ -142,6 +149,60 @@ def load_tensor(
                 )
             arr = arr.view(want)
     return arr
+
+
+def npy_payload_offset(
+    path: str | os.PathLike, shape: tuple[int, ...], dtype: str
+) -> int | None:
+    """Byte offset of a ``.npy`` file's payload when its bytes are exactly
+    a C-order ``shape`` array of ``dtype`` as :func:`load_tensor` serves it
+    (the header's shape, C order, and the itemsize ``load_tensor`` views
+    across — bf16/fp8 are stored as void), else None."""
+    fmt = np.lib.format
+    with open(path, "rb") as f:
+        version = fmt.read_magic(f)
+        if version == (1, 0):
+            stored_shape, fortran, stored = fmt.read_array_header_1_0(f)
+        elif version == (2, 0):
+            stored_shape, fortran, stored = fmt.read_array_header_2_0(f)
+        else:
+            return None
+        offset = f.tell()
+    if (
+        fortran
+        or stored.hasobject
+        or tuple(stored_shape) != tuple(shape)
+        or stored.itemsize != resolve_dtype(dtype).itemsize
+    ):
+        return None
+    return offset
+
+
+def read_into(path: str | os.PathLike, offset: int, out: memoryview) -> None:
+    """Fill ``out`` with the file's bytes from ``offset`` on, straight into
+    the caller's buffer (no intermediate copy).  Short reads are resumed;
+    end of file before ``out`` is full raises, as ``np.load`` does on a
+    truncated file.
+
+    Each page of ``out`` is touched first, so a fresh buffer's page faults
+    are taken in user space, where they run in parallel across threads; a
+    sandboxed kernel (gVisor) serializes the faults a read syscall takes.
+    On a TPU v5e host, 16 threads read fresh pages at 1.1-1.2 GB/s plain
+    and at 1.35-1.4 GB/s touched first."""
+    np.frombuffer(out, np.uint8)[::PAGESIZE] = 0
+    fd = os.open(path, os.O_RDONLY)
+    try:
+        done = 0
+        while done < len(out):
+            n = os.preadv(fd, [out[done:]], offset + done)
+            if n == 0:
+                raise ValueError(
+                    f"{path}: file ends {len(out) - done} bytes short of the "
+                    f"payload range at {offset}+{len(out)}"
+                )
+            done += n
+    finally:
+        os.close(fd)
 
 
 def open_memmap(

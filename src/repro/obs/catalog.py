@@ -125,6 +125,8 @@ COUNTERS: dict[str, str] = {
     "restore.count": "restore() calls",
     "restore.region_fragments": "fragments feeding consolidated regions",
     "restore.region_reads": "consolidated region reads",
+    "restore.whole_fragment_bytes": "bytes read straight from whole raw shard files",
+    "restore.whole_fragment_reads": "regions read straight from one whole raw shard file",
     "save.bytes_written": "bytes written by one save",
     "save.shards_inherited": "delta save: shards inherited from the base",
     "save.shards_written": "shards physically written",

@@ -34,10 +34,12 @@ def _plan(mesh, specs) -> ShardingPlan:
 
 
 def _random_state(specs, seed=0):
+    from repro.core.tensor_io import resolve_dtype
+
     rng = np.random.default_rng(seed)
     return {
         n: {
-            k: rng.normal(size=s.runtime_shape).astype(np.float32)
+            k: rng.normal(size=s.runtime_shape).astype(resolve_dtype(s.states[k].dtype))
             for k in STATE_KINDS
         }
         for n, s in specs.items()
@@ -372,3 +374,240 @@ def test_invalidate_respects_path_boundaries():
     assert "/ck/run1/ranks/r0/a.npy" not in eng.handles
     assert "/ck/run10/ranks/r0/a.npy" in eng.handles
     eng.close()
+
+
+# ---------------------------------------------------------------------------
+# Whole-fragment reads: a region served whole by one raw shard file is read
+# straight into its destination buffer, in byte ranges over the pool
+# ---------------------------------------------------------------------------
+
+_SQUARE = MeshSpec.from_dict({"data": 1, "model": 1})
+
+
+def _full(shape):
+    return tuple(slice(0, n) for n in shape)
+
+
+@pytest.mark.parametrize(
+    "dtype,shape,range_bytes",
+    [
+        ("float32", (7, 13), 64),
+        ("bfloat16", (5, 9), 16),
+        ("int8", (3, 11), 8),
+        ("float32", (), 64),
+        ("float32", (0, 4), 64),
+        ("float32", (10, 37), 96),  # 1480 bytes: 15 full ranges + 40 bytes
+    ],
+    ids=["float32", "bfloat16", "int8", "scalar", "zero_size", "ragged_ranges"],
+)
+def test_whole_fragment_read_matches_np_load(tmp_path, monkeypatch, dtype, shape, range_bytes):
+    """The range reads into one destination buffer return exactly the bytes
+    (and dtype) the ``np.load`` path serves, for every width the format
+    stores, a 0-d and a zero-size tensor, and a payload that is not a whole
+    number of ranges."""
+    import repro.ckpt.restore as restore
+    from repro.core.tensor_io import load_tensor, npy_payload_offset, resolve_dtype, save_tensor
+
+    monkeypatch.setattr(restore, "READ_RANGE_BYTES", range_bytes)
+    rng = np.random.default_rng(len(shape) * 7 + range_bytes)
+    arr = (rng.normal(size=shape) * 50).astype(resolve_dtype(dtype))
+    path = tmp_path / "t.npy"
+    save_tensor(path, arr)
+    offset = npy_payload_offset(path, shape, dtype)
+    assert offset is not None
+
+    def reader(*_):
+        raise AssertionError("a whole-file region must not take the reader path")
+
+    with CheckpointEngine(workers=3) as eng:
+        (got,) = restore._prefetch(
+            reader, lambda *_: (path, offset), StateKind.FP32,
+            [("t", dtype, _full(shape))], eng,
+        )
+    want = load_tensor(path, dtype=dtype, mmap=False)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+
+
+def test_whole_fragment_truncated_shard_raises(tmp_path):
+    """A shard file cut short raises out of the restore, as np.load does,
+    instead of handing back a partly filled buffer."""
+    from repro.ckpt.restore import state_from_dist
+    from repro.ckpt.saver import write_distributed
+
+    specs = {"w": uniform_param_spec("w", (64, 32), [DimSpec(), DimSpec()])}
+    write_distributed(_random_state(specs), _plan(_SQUARE, specs), 1, tmp_path / "ck", workers=1)
+    ck = DistCheckpoint.open(tmp_path / "ck")
+    path = ck.shard_path(0, "w", StateKind.EXP_AVG)
+    with open(path, "r+b") as f:
+        f.truncate(path.stat().st_size - 100)
+    jmesh = make_mesh((1, 1), ("data", "model"))
+    with CheckpointEngine(workers=2) as eng:
+        with pytest.raises(ValueError, match="short"):
+            state_from_dist(ck, _plan(_SQUARE, specs), jmesh, engine=eng)
+
+
+def _coded_source(tmp_path):
+    from repro.ckpt.restore import state_from_dist
+    from repro.ckpt.saver import write_distributed
+    from repro.core.codec import CodecPolicy
+
+    specs = {"w": uniform_param_spec("w", (8, 64), [DimSpec(), DimSpec()])}
+    plan = _plan(_SQUARE, specs)
+    codec = CodecPolicy("int8:b64", "int8:b64", "int8:b64", allow_lossy_params=True)
+    write_distributed(_random_state(specs), plan, 1, tmp_path / "ck", workers=1, codec=codec)
+    ck = DistCheckpoint.open(tmp_path / "ck")
+    assert ck.manifest.shard_codecs, "every shard is coded"
+    return ck, plan, lambda eng, jmesh: state_from_dist(ck, plan, jmesh, engine=eng)
+
+
+def _stream_source(tmp_path):
+    from repro.ckpt.restore import _whole_fragment_locator, state_from_stream
+    from repro.ckpt.saver import write_distributed
+    from repro.core.plan import TargetSpec, stream_transforms
+
+    src = {"w": uniform_param_spec("w", (8, 6), [DimSpec(("data",)), DimSpec()])}
+    tgt = {"w": uniform_param_spec("w", (8, 6), [DimSpec(), DimSpec()])}
+    src_mesh = MeshSpec.from_dict({"data": 2, "model": 1})
+    write_distributed(_random_state(src), _plan(src_mesh, src), 1, tmp_path / "ck", workers=1)
+    ck = DistCheckpoint.open(tmp_path / "ck")
+    plan = _plan(_SQUARE, tgt)
+    transforms = stream_transforms(ck.manifest, TargetSpec(_SQUARE, tgt))
+    with CheckpointEngine(workers=1) as eng:
+        locate = _whole_fragment_locator(ck, eng, plan, transforms)
+        # a region that covers part of one fragment, and one that unions two
+        assert locate("w", StateKind.FP32, (slice(0, 2), slice(0, 6)), "float32") is None
+        assert locate("w", StateKind.FP32, _full((8, 6)), "float32") is None
+        # the same fragment's own whole region does qualify
+        assert locate("w", StateKind.FP32, (slice(0, 4), slice(0, 6)), "float32") is not None
+    return ck, plan, lambda eng, jmesh: state_from_stream(ck, plan, jmesh, transforms, engine=eng)
+
+
+def _hot_source(tmp_path):
+    from repro.ckpt.restore import state_from_source
+    from repro.hot import HotTier
+
+    specs = {"w": uniform_param_spec("w", (8, 6), [DimSpec(), DimSpec()])}
+    plan = _plan(_SQUARE, specs)
+    hs, _ = HotTier(replication=1).capture(_random_state(specs), plan, 3)
+    return hs, plan, lambda eng, jmesh: state_from_source(hs, plan, jmesh, engine=eng)
+
+
+@pytest.mark.parametrize("build", [_coded_source, _stream_source, _hot_source],
+                         ids=["int8_codec", "reshard_stream_union", "hot_snapshot"])
+def test_whole_fragment_read_does_not_engage(tmp_path, build):
+    """Coded shards, regions that are not one whole fragment, and in-memory
+    sources keep the fragment-union path: no whole-fragment read, and the
+    state equals that path's region reads."""
+    import jax
+
+    import repro.obs as obs
+    from repro.ckpt.restore import read_region_from_source
+
+    source, plan, restore = build(tmp_path)
+    jmesh = make_mesh((1, 1), ("data", "model"))
+    with CheckpointEngine(workers=2) as eng, obs.enabled() as tracer:
+        state = restore(eng, jmesh)
+    counters = tracer.counters()
+    assert counters.get("restore.whole_fragment_reads", 0) == 0
+    assert counters.get("restore.whole_fragment_bytes", 0) == 0
+    assert counters.get("restore.region_reads", 0) > 0
+    with CheckpointEngine(workers=1) as eng:
+        for field, kind in (("params", StateKind.FP32), ("exp_avg", StateKind.EXP_AVG),
+                            ("exp_avg_sq", StateKind.EXP_AVG_SQ)):
+            leaf = jax.tree.leaves(getattr(state, field))[0]
+            spec = plan.param_specs["w"]
+            want = read_region_from_source(
+                source, "w", kind, _full(spec.runtime_shape), spec.states[kind].dtype,
+                engine=eng,
+            )
+            np.testing.assert_array_equal(np.asarray(leaf), want)
+
+
+def test_direct_restore_reads_every_region_whole(tmp_path):
+    """A DIRECT restore of a small model (float32, bfloat16, int8, 0-d and
+    fused-QKV parameters; the last is one file of three sub-fragment
+    entries) reads every region straight from its shard file: one
+    whole-fragment read per region, every payload byte counted, no
+    fragment-union read, nothing through the handle cache; bit for bit."""
+    import jax
+
+    import repro.obs as obs
+    from repro.ckpt.restore import state_from_dist
+    from repro.ckpt.saver import write_distributed
+    from repro.core.tensor_io import npy_payload_offset
+
+    specs = {
+        "emb": uniform_param_spec("emb", (24, 16), [DimSpec(("model",)), DimSpec()]),
+        "w": uniform_param_spec("w", (3, 16, 40), [DimSpec(), DimSpec(), DimSpec(("model",))]),
+        "w_bf16": uniform_param_spec("w_bf16", (16, 8), [DimSpec(), DimSpec()],
+                                     dtype="bfloat16"),
+        "w_int8": uniform_param_spec("w_int8", (5, 7), [DimSpec(), DimSpec()], dtype="int8"),
+        "scale": uniform_param_spec("scale", (), []),
+        "wqkv": uniform_param_spec(
+            "wqkv", (12, 6),
+            [DimSpec(("model",), (SubFragment("q", 8), SubFragment("k", 2), SubFragment("v", 2))),
+             DimSpec()],
+            kind="fused_qkv",
+        ),
+    }
+    plan = _plan(_SQUARE, specs)
+    snap = _random_state(specs, seed=21)
+    write_distributed(snap, plan, 4, tmp_path / "ck", workers=2)
+    ck = DistCheckpoint.open(tmp_path / "ck")
+    payload = 0
+    for name, spec in specs.items():
+        for kind in STATE_KINDS:
+            path = ck.shard_path(0, name, kind)
+            shape = spec.layout_for(kind, _SQUARE).local_shape
+            payload += path.stat().st_size - npy_payload_offset(path, shape, spec.states[kind].dtype)
+
+    jmesh = make_mesh((1, 1), ("data", "model"))
+    with CheckpointEngine(workers=4) as eng, obs.enabled() as tracer:
+        state = state_from_dist(ck, plan, jmesh, engine=eng)
+        assert eng.handles.misses == 0 and len(eng.handles) == 0
+    counters = tracer.counters()
+    assert counters["restore.whole_fragment_reads"] == len(specs) * len(STATE_KINDS)
+    assert counters["restore.whole_fragment_bytes"] == payload
+    assert counters.get("restore.region_reads", 0) == 0
+    for field, kind in (("params", StateKind.FP32), ("exp_avg", StateKind.EXP_AVG),
+                        ("exp_avg_sq", StateKind.EXP_AVG_SQ)):
+        tree = getattr(state, field)
+        for name in specs:
+            got = np.asarray(tree[name])
+            assert got.dtype == snap[name][kind].dtype
+            assert got.tobytes() == snap[name][kind].tobytes()
+
+
+def test_whole_fragment_ranges_outnumber_workers(tmp_path, monkeypatch):
+    """Many more range jobs than pool threads still complete: range jobs
+    are enumerated up front and never wait on the pool they run in."""
+    import jax
+
+    import repro.ckpt.restore as restore
+    from repro.ckpt.saver import write_distributed
+
+    monkeypatch.setattr(restore, "READ_RANGE_BYTES", 256)
+    specs = {
+        "a": uniform_param_spec("a", (32, 40), [DimSpec(), DimSpec()]),
+        "b": uniform_param_spec("b", (16, 24), [DimSpec(), DimSpec()]),
+    }
+    plan = _plan(_SQUARE, specs)
+    snap = _random_state(specs, seed=5)
+    write_distributed(snap, plan, 1, tmp_path / "ck", workers=1)
+    ck = DistCheckpoint.open(tmp_path / "ck")
+    jmesh = make_mesh((1, 1), ("data", "model"))
+    out: dict = {}
+    eng = CheckpointEngine(workers=2)
+    t = threading.Thread(
+        target=lambda: out.setdefault("s", restore.state_from_dist(ck, plan, jmesh, engine=eng)),
+        daemon=True,
+    )
+    t.start()
+    t.join(120)
+    # a deadlocked pool is left to the daemon threads: closing it would hang
+    assert not t.is_alive(), "whole-fragment range jobs deadlocked the pool"
+    eng.close()
+    for name in specs:
+        got = np.asarray(jax.tree.leaves(out["s"].exp_avg_sq)[sorted(specs).index(name)])
+        np.testing.assert_array_equal(got, snap[name][StateKind.EXP_AVG_SQ])
