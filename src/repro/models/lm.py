@@ -513,17 +513,19 @@ class LM:
         di = s.d_inner(d)
         nh = s.n_heads(d)
         g, n = s.n_groups, s.d_state
-        h = rms_norm(x, p["norm"], cfg.norm_eps)
-        zxbcdt = jnp.einsum("bsd,df->bsf", h, p["in_proj"].astype(h.dtype))
-        z, xbc, dt = jnp.split(zxbcdt, [di, 2 * di + 2 * g * n], axis=-1)
+        with jax.named_scope("mamba.in_proj"):
+            h = rms_norm(x, p["norm"], cfg.norm_eps)
+            zxbcdt = jnp.einsum("bsd,df->bsf", h, p["in_proj"].astype(h.dtype))
+            z, xbc, dt = jnp.split(zxbcdt, [di, 2 * di + 2 * g * n], axis=-1)
         conv_tail = xbc[:, -(s.d_conv - 1):, :] if return_state else None
-        cw = p["conv_w"].astype(h.dtype)
-        cb = p["conv_b"].astype(h.dtype)
-        if conv0 is not None:
-            xbc_ext = jnp.concatenate([conv0, xbc], axis=1)
-            xbc = causal_conv1d(xbc_ext, cw, cb)[:, s.d_conv - 1:]
-        else:
-            xbc = causal_conv1d(xbc, cw, cb)
+        with jax.named_scope("mamba.conv"):
+            cw = p["conv_w"].astype(h.dtype)
+            cb = p["conv_b"].astype(h.dtype)
+            if conv0 is not None:
+                xbc_ext = jnp.concatenate([conv0, xbc], axis=1)
+                xbc = causal_conv1d(xbc_ext, cw, cb)[:, s.d_conv - 1:]
+            else:
+                xbc = causal_conv1d(xbc, cw, cb)
         xin, bmat, cmat = jnp.split(xbc, [di, di + g * n], axis=-1)
         xin = xin.reshape(b, sl, nh, s.head_dim)
         bmat = bmat.reshape(b, sl, g, n)
@@ -535,9 +537,11 @@ class LM:
             chunk //= 2
         y, h_final = ssd_chunked(xin, dt, a, bmat, cmat, chunk=chunk, h0=h0)
         y = y + xin * p["d_skip"].astype(y.dtype)[None, None, :, None]
-        y = y.reshape(b, sl, di) * jax.nn.silu(z)
-        y = rms_norm(y, p["ssm_norm"], cfg.norm_eps)
-        out = jnp.einsum("bsf,fd->bsd", y, p["out_proj"].astype(y.dtype))
+        with jax.named_scope("mamba.gate_norm"):
+            y = y.reshape(b, sl, di) * jax.nn.silu(z)
+            y = rms_norm(y, p["ssm_norm"], cfg.norm_eps)
+        with jax.named_scope("mamba.out_proj"):
+            out = jnp.einsum("bsf,fd->bsd", y, p["out_proj"].astype(y.dtype))
         state = (h_final, conv_tail) if return_state else None
         return x + self.shard(out, ("batch", "seq", "embed")), state
 

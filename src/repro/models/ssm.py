@@ -63,6 +63,10 @@ def ssd_chunked(x, dt, a, bmat, cmat, *, chunk: int, h0=None):
     """Chunked SSD: intra-chunk masked matmuls + inter-chunk state scan.
 
     Matches :func:`ssd_recurrent` (property-tested).  Returns (y, h_final).
+    Finite forward and backward for any chunk length and any ``dt·A <= 0``.
+    Its parts carry the named scopes ``ssd.intra``, ``ssd.states``,
+    ``ssd.scan`` and ``ssd.inter``, so a device trace can attribute their
+    time.
     """
     bsz, s, h, p = x.shape
     n = bmat.shape[-1]
@@ -83,20 +87,26 @@ def ssd_chunked(x, dt, a, bmat, cmat, *, chunk: int, h0=None):
     total = cum[:, :, -1, :]                          # [B,nc,H]
 
     # ---- intra-chunk (quadratic in chunk length; pure matmul) -------------
-    # L[i,j] = exp(cum_i - cum_j) for i >= j (segment decay), else 0
-    seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]      # [B,nc,Q,Q,H]
-    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
-    l_mask = jnp.where(tri[None, None, :, :, None], jnp.exp(seg), 0.0)
-    cb = jnp.einsum("bcqhn,bckhn->bcqkh", cq.astype(jnp.float32), bq.astype(jnp.float32))
-    xdt = xq.astype(jnp.float32) * dtq[..., None]
-    y_intra = jnp.einsum("bcqkh,bckhp->bcqhp", cb * l_mask, xdt)
+    # L[i,j] = exp(cum_i - cum_j) for i >= j (segment decay), else 0.  The
+    # mask goes in before the exponential: above the diagonal cum_i - cum_j
+    # is positive and overflows float32 at the published chunk (256) and
+    # A/dt init, and a masked inf turns the gradient into 0 · inf = NaN.
+    with jax.named_scope("ssd.intra"):
+        seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]      # [B,nc,Q,Q,H]
+        tri = jnp.tril(jnp.ones((chunk, chunk), bool))
+        l_mask = jnp.exp(jnp.where(tri[None, None, :, :, None], seg, -jnp.inf))
+        cb = jnp.einsum("bcqhn,bckhn->bcqkh", cq.astype(jnp.float32), bq.astype(jnp.float32))
+        xdt = xq.astype(jnp.float32) * dtq[..., None]
+        y_intra = jnp.einsum("bcqkh,bckhp->bcqhp", cb * l_mask, xdt)
 
     # ---- chunk boundary states --------------------------------------------
     # state contribution of chunk c: sum_j exp(total - cum_j) dt_j x_j B_j^T
-    decay_to_end = jnp.exp(total[:, :, None, :] - cum)       # [B,nc,Q,H]
-    s_chunk = jnp.einsum(
-        "bcqhp,bcqhn->bchpn", xdt * decay_to_end[..., None], bq.astype(jnp.float32)
-    )
+    # (total - cum_j <= 0, as is cum below: no exponential here can overflow)
+    with jax.named_scope("ssd.states"):
+        decay_to_end = jnp.exp(total[:, :, None, :] - cum)       # [B,nc,Q,H]
+        s_chunk = jnp.einsum(
+            "bcqhp,bcqhn->bchpn", xdt * decay_to_end[..., None], bq.astype(jnp.float32)
+        )
 
     h_init = jnp.zeros((bsz, h, p, n), jnp.float32) if h0 is None else h0
 
@@ -105,17 +115,19 @@ def ssd_chunked(x, dt, a, bmat, cmat, *, chunk: int, h0=None):
         hnew = hprev * jnp.exp(tot_c)[:, :, None, None] + s_c
         return hnew, hprev
 
-    (h_final, h_prevs) = jax.lax.scan(
-        boundary,
-        h_init,
-        (s_chunk.transpose(1, 0, 2, 3, 4), total.transpose(1, 0, 2)),
-    )
-    h_prevs = h_prevs.transpose(1, 0, 2, 3, 4)               # [B,nc,H,P,N]
+    with jax.named_scope("ssd.scan"):
+        (h_final, h_prevs) = jax.lax.scan(
+            boundary,
+            h_init,
+            (s_chunk.transpose(1, 0, 2, 3, 4), total.transpose(1, 0, 2)),
+        )
+        h_prevs = h_prevs.transpose(1, 0, 2, 3, 4)               # [B,nc,H,P,N]
 
     # ---- inter-chunk: y += C_t · exp(cum_t) · h_prev ----------------------
-    y_inter = jnp.einsum(
-        "bcqhn,bchpn->bcqhp", cq.astype(jnp.float32) * jnp.exp(cum)[..., None], h_prevs
-    )
+    with jax.named_scope("ssd.inter"):
+        y_inter = jnp.einsum(
+            "bcqhn,bchpn->bcqhp", cq.astype(jnp.float32) * jnp.exp(cum)[..., None], h_prevs
+        )
 
     y = (y_intra + y_inter).reshape(bsz, s, h, p).astype(x.dtype)
     return y, h_final

@@ -48,7 +48,7 @@ def test_full_config_matches_assignment(arch):
     cfg = get_config(arch)
     table = {
         "llama-3.2-vision-11b": (40, 4096, 32, 8, 14336, 128256),
-        "mamba2-130m": (24, 768, None, None, 0, 50280),
+        "mamba2-130m": (24, 768, None, None, 0, 50288),
         "deepseek-v2-236b": (60, 5120, 128, 128, None, 102400),
         "mixtral-8x22b": (56, 6144, 48, 8, 16384, 32768),
         "jamba-1.5-large-398b": (72, 8192, 64, 8, 24576, 65536),
