@@ -28,8 +28,11 @@ handle cache, and ``_build_state`` prefetches every device region
 concurrently over the engine's worker pool.  A region that one raw shard
 file on disk holds whole skips the handle cache: its payload is read
 straight into the region's staging buffer in byte ranges, jobs of the same
-pool (``_prefetch``).  ``CheckpointEngine(workers=1)`` degrades to the exact
-serial order, byte-identical by construction.
+pool (``_prefetch``).  The state kinds stage one after another with the
+same shapes, so for the length of a restore the engine's arena keeps one
+kind's staging storage (``BufferArena.hold``) and each kind stages into
+the pages of the kind before it.  ``CheckpointEngine(workers=1)``
+degrades to the exact serial order, byte-identical by construction.
 """
 
 from __future__ import annotations
@@ -46,7 +49,7 @@ import repro.obs as obs
 from repro.core.atoms import UcpCheckpoint
 from repro.core.convert import assemble_atom
 from repro.core.dist_ckpt import DistCheckpoint
-from repro.core.engine import CheckpointEngine, default_engine
+from repro.core.engine import BufferArena, CheckpointEngine, default_engine
 from repro.core.ops import clip_region_to_logical, read_runtime_region
 from repro.core.patterns import ParamTransform, StateKind, TransformClass
 from repro.core.pytree import unflatten_from_paths
@@ -179,13 +182,13 @@ def _build_trees(
         (n, s) for n, s in plan.param_specs.items() if names is None or n in names
     ]
 
-    trees: dict[str, dict[str, jax.Array]] = {}
+    # Enumerate every (param, device-region) each state kind will request,
+    # so a kind's reads can all start concurrently up front: the
+    # make_array callbacks then serve from the prefetch table instead of
+    # reading serially one device region at a time.  Batching per kind
+    # bounds peak prefetch memory to one state copy.
+    plans: list[tuple[dict[str, NamedSharding], list]] = []
     for field, kind in fields:
-        # Enumerate every (param, device-region) this state kind will
-        # request and issue the reads concurrently up front; the
-        # make_array callbacks below then serve from the prefetch table
-        # instead of reading serially one device region at a time.
-        # Batching per kind bounds peak prefetch memory to one state copy.
         shardings: dict[str, NamedSharding] = {}
         jobs: list[tuple[str, str, tuple[slice, ...]]] = []
         seen: set[tuple] = set()
@@ -199,41 +202,82 @@ def _build_trees(
                 if key not in seen:
                     seen.add(key)
                     jobs.append((name, spec.states[kind].dtype, canon))
-        with obs.span("restore.prefetch", field=field, regions=len(jobs)):
-            results = _prefetch(reader, locate, kind, jobs, engine)
-        table = {
-            (n, tuple((r.start, r.stop) for r in canon)): arr
-            for (n, _, canon), arr in zip(jobs, results)
-        }
+        plans.append((shardings, jobs))
+    # Every kind but the last hands its staging storage to the next (same
+    # shapes), so the arena keeps the largest such kind's worth for the
+    # length of the restore instead of faulting fresh pages for each kind.
+    handoff = max(
+        (_staging_bytes(jobs) for _, jobs in plans[:-1]), default=0
+    ) if engine.use_arena else 0
 
-        flat: dict[str, jax.Array] = {}
-        with obs.span("restore.materialize", field=field):
-            for name, spec in param_items:
-                dtype = spec.states[kind].dtype
-                shape = tuple(spec.runtime_shape)
+    trees: dict[str, dict[str, jax.Array]] = {}
+    with engine.arena.hold(handoff):
+        for i, ((field, kind), (shardings, jobs)) in enumerate(zip(fields, plans)):
+            warm0, fresh0 = engine.arena.reuse_bytes, engine.arena.alloc_bytes
+            with obs.span("restore.prefetch", field=field, regions=len(jobs)) as sp:
+                results = _prefetch(reader, locate, kind, jobs, engine)
+                sp.set(warm_bytes=engine.arena.reuse_bytes - warm0,
+                       fresh_bytes=engine.arena.alloc_bytes - fresh0)
+            table = {
+                (n, tuple((r.start, r.stop) for r in canon)): arr
+                for (n, _, canon), arr in zip(jobs, results)
+            }
+            del results  # the table holds the only references now
 
-                def cb(index, _n=name, _k=kind, _d=dtype, _s=shape):
-                    canon = _canon_region(index, _s)
-                    arr = table.get((_n, tuple((r.start, r.stop) for r in canon)))
-                    if arr is None:  # region jax didn't pre-announce: read now
-                        arr = reader(_n, _k, canon, _d)
+            flat: dict[str, jax.Array] = {}
+            with obs.span("restore.materialize", field=field):
+                for name, spec in param_items:
+                    dtype = spec.states[kind].dtype
+                    shape = tuple(spec.runtime_shape)
+
+                    def cb(index, _n=name, _k=kind, _d=dtype, _s=shape):
+                        canon = _canon_region(index, _s)
+                        arr = table.get((_n, tuple((r.start, r.stop) for r in canon)))
+                        if arr is None:  # region jax didn't pre-announce: read now
+                            arr = reader(_n, _k, canon, _d)
+                        if stats is not None:
+                            stats.bytes_read += arr.nbytes
+                        obs.add("restore.bytes_read", arr.nbytes)
+                        return arr
+
+                    flat[name] = jax.make_array_from_callback(
+                        shape, shardings[name], cb
+                    )
                     if stats is not None:
-                        stats.bytes_read += arr.nbytes
-                    obs.add("restore.bytes_read", arr.nbytes)
-                    return arr
-
-                flat[name] = jax.make_array_from_callback(
-                    shape, shardings[name], cb
-                )
-                if stats is not None:
-                    stats.arrays += 1
-                obs.add("restore.arrays")
-                # jax copied the callback arrays into its own buffers; the
-                # staging storage can back the next parameter's reads.
-                for key in [k for k in table if k[0] == name]:
-                    engine.recycle(table.pop(key))
-        trees[field] = flat
+                        stats.arrays += 1
+                    obs.add("restore.arrays")
+                    # Offer the staging storage back; the arena takes it
+                    # only once jax no longer holds it (the transfer done).
+                    for key in [k for k in table if k[0] == name]:
+                        engine.recycle(table.pop(key))
+                if handoff and i < len(fields) - 1:
+                    # The next kind's allocations then find this kind's
+                    # storage free.
+                    _release_staging(list(flat.values()))
+            trees[field] = flat
     return trees
+
+
+def _release_staging(arrays: list[jax.Array]) -> None:
+    """Wait until jax no longer holds the host buffers ``arrays`` were
+    placed from.  Placement returns before its host-to-device copies land;
+    once they have, jax drops each buffer through a deferred list that it
+    drains only on some later calls, so drain it here."""
+    from jax._src.lib import _jax
+
+    jax.block_until_ready(arrays)
+    _jax.collect_garbage()
+
+
+def _staging_bytes(jobs) -> int:
+    """Arena storage the ``(name, dtype, region)`` jobs of one kind take."""
+    return sum(
+        BufferArena.bucket(max(
+            math.prod(r.stop - r.start for r in region)
+            * resolve_dtype(dtype).itemsize, 1,
+        ))
+        for _, dtype, region in jobs
+    )
 
 
 def _prefetch(reader, locate, kind: StateKind, jobs, engine: CheckpointEngine) -> list:

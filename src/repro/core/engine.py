@@ -51,6 +51,7 @@ paths serve from disk and from the hot tier through one code path
 from __future__ import annotations
 
 import bisect
+import contextlib
 import math
 import os
 import sys
@@ -167,6 +168,11 @@ class BufferArena:
     will overwrite every element (fragments fully cover the region);
     contents of a recycled buffer are otherwise arbitrary, so callers must
     pass ``zero=True`` unless they fully overwrite.
+
+    **Retention is bounded** by ``max_bytes``; :meth:`hold` raises the
+    bound for the length of one operation whose later steps reuse its
+    earlier steps' buffers (a restore handing one state kind's staging to
+    the next), and trims back to ``max_bytes`` when it ends.
     """
 
     def __init__(self, max_bytes: int = 1 << 30):
@@ -176,13 +182,17 @@ class BufferArena:
         self._pending: list[np.ndarray] = []  #: guarded by self._lock -- recycled, chain maybe alive
         self._pooled_ids: set[int] = set()  #: guarded by self._lock -- ids parked in _free or _pending
         self._retained = 0  #: guarded by self._lock
+        self._held = 0  #: guarded by self._lock -- retention above max_bytes lent to open holds
         self.allocs = 0
         self.reuses = 0
+        self.alloc_bytes = 0  # requested bytes served from fresh storage
+        self.reuse_bytes = 0  # requested bytes served from recycled storage
 
     @staticmethod
-    def _bucket(nbytes: int) -> int:
-        """Round up to a power of two (min one page) so near-miss sizes
-        still reuse each other's storage; waste is bounded at 2x."""
+    def bucket(nbytes: int) -> int:
+        """The storage ``alloc`` takes for ``nbytes``: a power of two (min
+        one page), so near-miss sizes still reuse each other's storage;
+        waste is bounded at 2x."""
         size = 4096
         while size < nbytes:
             size <<= 1
@@ -190,13 +200,14 @@ class BufferArena:
 
     def _reap_locked(self) -> None:  # repro: holds[self._lock]
         """Move pending buffers whose view chains died onto the free lists."""
+        budget = self.max_bytes + self._held
         still: list[np.ndarray] = []
         for raw in self._pending:
             # References when the chain is dead: the _pending list, the
             # loop variable, and getrefcount's argument binding == 3.  A
             # live view (ours or an aliasing jax array's) adds a fourth.
             if sys.getrefcount(raw) <= 3:
-                if self._retained + raw.nbytes <= self.max_bytes:
+                if self._retained + raw.nbytes <= budget:
                     self._free.setdefault(raw.nbytes, []).append(raw)
                     self._retained += raw.nbytes
                 else:
@@ -205,10 +216,51 @@ class BufferArena:
                 still.append(raw)
         self._pending = still
 
+    @contextlib.contextmanager
+    def hold(self, nbytes: int):
+        """Retain up to ``nbytes`` more recycled storage while the block runs.
+
+        The pending buffers free at entry are reaped under the plain
+        ``max_bytes`` first, so what was recycled before the hold is kept
+        as without it.  On exit the free lists are trimmed back to the
+        bound, first fit in their order as the reap fills them: between
+        holds the arena keeps what it keeps without them.  Holds nest
+        additively; ``nbytes <= 0`` is no hold at all.
+        """
+        if nbytes <= 0:
+            yield
+            return
+        with self._lock:
+            self._reap_locked()
+            self._held += nbytes
+        try:
+            yield
+        finally:
+            with self._lock:
+                self._held -= nbytes
+                self._trim_locked()
+
+    def _trim_locked(self) -> None:  # repro: holds[self._lock]
+        """Drop free buffers until the retained bytes fit the bound."""
+        budget = self.max_bytes + self._held
+        if self._retained <= budget:
+            return
+        kept = 0
+        for stack in self._free.values():
+            keep = []
+            for raw in stack:
+                if kept + raw.nbytes <= budget:
+                    keep.append(raw)
+                    kept += raw.nbytes
+                else:
+                    self._pooled_ids.discard(id(raw))
+            stack[:] = keep
+        self._retained = kept
+
     def alloc(self, shape, dtype, *, zero: bool = True) -> np.ndarray:
         dt = resolve_dtype(dtype) if isinstance(dtype, str) else np.dtype(dtype)
         nbytes = math.prod(int(s) for s in shape) * dt.itemsize if shape else dt.itemsize
-        bucket = self._bucket(max(nbytes, 1))
+        bucket = self.bucket(max(nbytes, 1))
         raw = None
         with self._lock:
             self._reap_locked()
@@ -218,10 +270,14 @@ class BufferArena:
                 self._retained -= raw.nbytes
                 self._pooled_ids.discard(id(raw))
                 self.reuses += 1
+                self.reuse_bytes += nbytes
                 obs.add("engine.arena.reuse")
+                obs.add("engine.arena.reuse_bytes", nbytes)
             else:
                 self.allocs += 1
+                self.alloc_bytes += nbytes
                 obs.add("engine.arena.alloc")
+                obs.add("engine.arena.alloc_bytes", nbytes)
         if raw is None:
             raw = np.empty(bucket, np.uint8).view(_ArenaBuffer)
         # plain-ndarray view (consumers like np.save / jax shouldn't see the

@@ -39,7 +39,7 @@ SPANS: dict[str, str] = {
     "restore.consolidate": "restore, cross-shard regions consolidated",
     "restore.materialize": "restore, planned reads executed into arrays",
     "restore.plan": "restore, read plan computed from manifests",
-    "restore.prefetch": "restore, handle cache warmed for planned shards",
+    "restore.prefetch": "restore, one state kind's host region reads into staging buffers",
     "restore.tier": "one recovery-ladder attempt (hot / local / peer / disk)",
     "save.async_job": "AsyncSaver worker: one queued save end-to-end",
     "save.fsync": "save path, directory+file fsync barrier",
@@ -105,7 +105,9 @@ COUNTERS: dict[str, str] = {
     "convert.bytes_written": "bytes written by conversion",
     "convert.params": "parameters converted",
     "engine.arena.alloc": "buffer arena: fresh allocations",
+    "engine.arena.alloc_bytes": "buffer arena: requested bytes served from fresh storage",
     "engine.arena.reuse": "buffer arena: pooled-buffer reuses",
+    "engine.arena.reuse_bytes": "buffer arena: requested bytes served from recycled storage",
     "engine.index.build": "shard indexes built",
     "engine.index.hit": "shard index cache hits",
     "gc.collected_bytes": "bytes reclaimed by GC",

@@ -611,3 +611,233 @@ def test_whole_fragment_ranges_outnumber_workers(tmp_path, monkeypatch):
     for name in specs:
         got = np.asarray(jax.tree.leaves(out["s"].exp_avg_sq)[sorted(specs).index(name)])
         np.testing.assert_array_equal(got, snap[name][StateKind.EXP_AVG_SQ])
+
+
+# ---------------------------------------------------------------------------
+# Restore-scoped arena hold: one state kind's staging storage backs the next
+# ---------------------------------------------------------------------------
+
+_KIND_MAX = 128 << 10  # arena bound: two of a kind's four 64 KiB buffers
+
+
+def test_arena_hold_retains_then_trims():
+    """Inside a hold the arena keeps recycled storage beyond ``max_bytes``;
+    on exit it trims back to the bound.  ``hold(0)`` holds nothing, and
+    what was recycled before a hold is reaped under the plain bound."""
+    from repro.core.engine import BufferArena
+
+    arena = BufferArena(max_bytes=8192)
+    page = 4096
+
+    def cycle(n, settle=True):
+        """Stage ``n`` one-page buffers, recycle them, and reap."""
+        bufs = [arena.alloc((page,), np.uint8) for _ in range(n)]
+        for buf in bufs:
+            arena.recycle(buf)
+        del bufs, buf
+        if settle:
+            with arena._lock:
+                arena._reap_locked()
+        return arena._retained
+
+    assert cycle(4) == 2 * page
+    with arena.hold(0):
+        assert cycle(4) == 2 * page
+    cycle(4, settle=False)  # recycled before the hold, reaped at its entry
+    with arena.hold(4 * page):
+        assert arena._retained == 2 * page
+        assert cycle(6) == 6 * page
+        with arena.hold(page):
+            assert cycle(7) == 7 * page
+        assert arena._retained == 6 * page  # the inner exit trims to the outer bound
+    assert arena._retained == 2 * page
+    assert arena.reuse_bytes == (2 + 2 + 2 + 6) * page
+    assert arena.alloc_bytes == (4 + 2 + 2 + 4 + 1) * page
+
+
+def test_arena_concurrent_holds_keep_the_books():
+    """Threads (more than cores, short switch interval) open overlapping
+    holds while they stage and recycle buffers: when every hold has ended
+    the arena's bound is back to ``max_bytes``, its free lists hold each
+    buffer once, and its retained count is what the lists hold."""
+    import os
+    import sys
+
+    from repro.core.engine import BufferArena
+
+    arena = BufferArena(max_bytes=4 * 4096)
+    errors: list[BaseException] = []
+
+    def work(seed):
+        rng = np.random.default_rng(seed)
+        try:
+            for _ in range(40):
+                with arena.hold(int(rng.integers(0, 8)) * 4096):
+                    bufs = [arena.alloc((int(rng.integers(1, 3)) * 4096,), np.uint8)
+                            for _ in range(int(rng.integers(1, 6)))]
+                    for buf in bufs:
+                        buf[:] = seed
+                        arena.recycle(buf)
+                    del bufs, buf
+        except BaseException as e:  # reported by the main thread
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(i,), daemon=True)
+                   for i in range(2 * (os.cpu_count() or 2) + 2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert not any(t.is_alive() for t in threads), "arena holds deadlocked"
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors, errors
+    with arena._lock:
+        arena._reap_locked()
+        free = [raw for stack in arena._free.values() for raw in stack]
+        assert arena._held == 0
+        assert len({id(raw) for raw in free}) == len(free)
+        assert arena._retained == sum(raw.nbytes for raw in free) <= arena.max_bytes
+
+
+def _kinds_checkpoint(tmp_path):
+    from repro.ckpt.saver import write_distributed
+
+    specs = {f"w{i}": uniform_param_spec(f"w{i}", (64, 256), [DimSpec(), DimSpec()])
+             for i in range(4)}  # 64 KiB a region, 256 KiB a kind
+    plan = _plan(_SQUARE, specs)
+    snap = _random_state(specs, seed=16)
+    write_distributed(snap, plan, 2, tmp_path / "ck", workers=1)
+    return DistCheckpoint.open(tmp_path / "ck"), plan, snap
+
+
+@pytest.mark.parametrize("case", ["held", "hold_disabled", "consumer_aliases", "jax_consumer"])
+def test_restore_hands_staging_kind_to_kind(tmp_path, monkeypatch, case):
+    """A three-kind DIRECT restore with ``arena_max_bytes`` below one kind:
+    each kind stages into the storage of the kind before it (two thirds
+    of the staging bytes warm), where without the hold a kind gets at
+    most ``max_bytes`` warm.  After the restore the arena retains at most
+    ``max_bytes``, so the next restore's first kind reuses what it would
+    without the hold.  A staging buffer that a consumer still references
+    (jax's CPU backend may alias one) is never handed to the next kind:
+    its bytes stay what was handed over.  Every restore equals a serial
+    ``workers=1`` restore bit for bit.
+
+    On the chip the runtime copies each staging buffer to the device; the
+    consumer here copies too, so whether the CPU backend aliases a buffer
+    (by its alignment) does not decide the counts.  ``jax_consumer`` hands
+    jax the staging buffers themselves: a kind then reuses every buffer of
+    the kind before but those the restored arrays alias, which jax's CPU
+    copies release only through its deferred reference list."""
+    import contextlib
+
+    import jax
+
+    import repro.obs as obs
+    from repro.ckpt.restore import state_from_dist
+    from repro.core.engine import BufferArena
+
+    ck, plan, snap = _kinds_checkpoint(tmp_path)
+    jmesh = make_mesh((1, 1), ("data", "model"))
+    kind_bytes = 4 * (64 << 10)
+    with CheckpointEngine(workers=1) as eng:
+        serial = state_from_dist(ck, plan, jmesh, engine=eng)
+    for field, kind in (("params", StateKind.FP32), ("exp_avg", StateKind.EXP_AVG),
+                        ("exp_avg_sq", StateKind.EXP_AVG_SQ)):
+        for name, leaf in zip(sorted(snap), jax.tree.leaves(getattr(serial, field))):
+            assert np.asarray(leaf).tobytes() == snap[name][kind].tobytes()
+    want = jax.tree.leaves(serial)
+    if case == "hold_disabled":
+        monkeypatch.setattr(BufferArena, "hold", lambda self, nbytes: contextlib.nullcontext())
+    held: list[tuple[np.ndarray, np.ndarray]] = []
+    aliased: list[int] = []  # jax_consumer: regions a restored array aliases, per kind
+    real = jax.make_array_from_callback
+
+    def consumer(shape, sharding, cb):
+        if case == "jax_consumer":
+            staged = []
+            arr = real(shape, sharding, lambda index: staged.append(cb(index)) or staged[-1])
+            ptr = arr.unsafe_buffer_pointer()  # one device: one buffer
+            aliased.append(sum(x.__array_interface__["data"][0] == ptr for x in staged))
+            return arr
+
+        def copying(index):
+            staged = cb(index)
+            if case == "consumer_aliases":
+                held.append((staged.view(), staged.copy()))
+            return np.array(staged, copy=True)
+        return real(shape, sharding, copying)
+
+    monkeypatch.setattr(jax, "make_array_from_callback", consumer)
+    with CheckpointEngine(workers=4, arena_max_bytes=_KIND_MAX) as eng:
+        warm, counters = [], []
+        for _ in range(2):
+            with obs.enabled() as tracer:
+                got = jax.tree.leaves(state_from_dist(ck, plan, jmesh, engine=eng))
+            assert eng.arena._retained <= _KIND_MAX
+            spans = [s for s in tracer.span_records() if s["name"] == "restore.prefetch"]
+            assert [s["attrs"]["field"] for s in spans] == ["params", "exp_avg", "exp_avg_sq"]
+            for s in spans:
+                assert s["attrs"]["warm_bytes"] + s["attrs"]["fresh_bytes"] == kind_bytes
+            warm.append([s["attrs"]["warm_bytes"] for s in spans])
+            counters.append(tracer.counters())
+            assert len(got) == len(want) > 0
+            for a, b in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            del got
+
+    first = counters[0]
+    total = first.get("engine.arena.reuse_bytes", 0) + first.get("engine.arena.alloc_bytes", 0)
+    assert total == 3 * kind_bytes
+    assert warm[0][0] == 0  # a new engine: the first kind faults fresh pages
+    if case == "jax_consumer":
+        n = len(plan.param_specs)
+        per_kind = [sum(aliased[k * n:(k + 1) * n]) for k in range(6)]
+        for r in range(2):  # the second also finds what the first's arrays held
+            for k in (1, 2):
+                want_warm = kind_bytes - per_kind[3 * r + k - 1] * (64 << 10)
+                assert warm[r][k] == want_warm if r == 0 else warm[r][k] >= want_warm
+        return
+    # the next restore's first kind: max_bytes of the last kind's storage, first fit
+    assert warm[1][0] == (_KIND_MAX if case != "consumer_aliases" else 0)
+    if case == "held":
+        assert warm[0][1:] == [kind_bytes, kind_bytes]
+        assert first["engine.arena.reuse_bytes"] >= 2 * total / 3
+    elif case == "hold_disabled":
+        assert warm[0][1:] == [_KIND_MAX, _KIND_MAX]
+    else:
+        assert warm == [[0, 0, 0], [0, 0, 0]]
+        assert len(held) == 2 * 3 * len(plan.param_specs)
+        for view, handed in held:
+            assert view.tobytes() == handed.tobytes()
+
+
+def test_release_staging_frees_placed_buffers():
+    """Once ``_release_staging`` returns, jax holds none of the host buffers
+    the arrays were placed from, so the arena hands their storage to the
+    next allocation.  A 64 MiB buffer is copied to the device after the
+    placement call returns and released through jax's deferred list."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    from repro.ckpt.restore import _release_staging
+    from repro.core.engine import BufferArena
+
+    arena = BufferArena()
+    n = 16 << 20
+    staged = arena.alloc((n,), np.float32, zero=False)
+    staged[...] = 1.5
+    # one element in: never 64-byte aligned, so the CPU backend copies it
+    src = staged[1:]
+    arr = jax.make_array_from_callback(
+        src.shape, SingleDeviceSharding(jax.devices()[0]), lambda index: src[index]
+    )
+    arena.recycle(staged)
+    del staged, src
+    _release_staging([arr])
+    arena.alloc((n,), np.float32, zero=False)
+    assert arena.reuses == 1
+    assert float(arr[-1]) == 1.5
