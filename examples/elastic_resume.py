@@ -70,6 +70,7 @@ def hot_tier_demo() -> None:
     from repro.configs import ParallelismConfig, get_config, reduced
     from repro.core.layout import MeshSpec
     from repro.ckpt.manager import CheckpointManager
+    from repro.ckpt.policy import CheckpointPolicy
     from repro.dist.sharding import make_plan, vocab_multiple
     from repro.elastic.resume import ElasticEvent, hot_recover
     from repro.launch.mesh import make_mesh
@@ -87,8 +88,10 @@ def hot_tier_demo() -> None:
     with tempfile.TemporaryDirectory() as tmp:
         mgr = CheckpointManager(
             f"{tmp}/job", plan,
-            hot_interval=1, save_interval=4,  # hot every step, disk every 4th
-            hot_replication=1, async_save=False,
+            policy=CheckpointPolicy(
+                hot_interval=1, save_interval=4,  # hot every step, disk every 4th
+                hot_replication=1, async_save=False,
+            ),
         )
         for step in (1, 2, 3):  # three hot snapshots, nothing on disk yet
             mgr.save(state, step)

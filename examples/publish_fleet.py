@@ -26,6 +26,7 @@ import jax
 import numpy as np
 
 from repro.configs import ParallelismConfig, TrainConfig, get_config, reduced
+from repro.ckpt.policy import CheckpointPolicy
 from repro.ckpt.restore import state_from_dist
 from repro.core import DistCheckpoint, MeshSpec
 from repro.core.engine import CheckpointEngine
@@ -58,8 +59,10 @@ def main() -> None:
         trainer = Trainer.create(
             cfg, ParallelismConfig(), TrainConfig(warmup_steps=2),
             train_mesh, batch_size=8, seq_len=32,
-            ckpt_dir=f"{tmp}/job", save_interval=5, async_save=False,
-            registry=registry,
+            ckpt_dir=f"{tmp}/job",
+            policy=CheckpointPolicy(
+                save_interval=5, async_save=False, registry=registry,
+            ),
         )
         print("training: data=2,model=2 — every committed save is published")
         state, _ = trainer.init_or_restore()

@@ -14,6 +14,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 
 from repro.configs import ParallelismConfig, TrainConfig, get_config, reduced
+from repro.ckpt.policy import CheckpointPolicy
 from repro.core.atoms import UcpCheckpoint
 from repro.core.convert import convert_to_ucp
 from repro.core.dist_ckpt import DistCheckpoint
@@ -31,7 +32,8 @@ def main() -> None:
         trainer = Trainer.create(
             cfg, ParallelismConfig(), TrainConfig(warmup_steps=2),
             jmesh, batch_size=4, seq_len=32,
-            ckpt_dir=f"{tmp}/run", save_interval=5, async_save=False,
+            ckpt_dir=f"{tmp}/run",
+            policy=CheckpointPolicy(save_interval=5, async_save=False),
         )
         state, _ = trainer.init_or_restore()
         state, hist = trainer.run(state, 0, 10, log=lambda r: print(

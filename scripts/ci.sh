@@ -9,19 +9,13 @@
 # (multi-second subprocess/e2e/property tests).  The tier-1 gate
 # (ROADMAP.md) remains the FULL suite — run ci.sh without --fast before
 # shipping (the main/nightly CI lane does).
-# Stage 3 — benchmark smoke: a small-size save-cost + hot-tier run with
-# --json, compared against the committed BENCH_checkpointing.json baseline
-# within a loose tolerance (scripts/bench_compare.py) so an
-# order-of-magnitude perf regression or a broken recording fails in CI
-# rather than on the next real benchmark run.
-#
-# Stage 4 — obs smoke: run elastic-resume phase 1 with --trace and
+# Stage 3 — obs smoke: run elastic-resume phase 1 with --trace and
 # validate the exported Chrome trace-event file (schema, event/containment
 # invariants, non-trivial span count) via repro.obs.validate_chrome_trace,
 # so a broken exporter or an instrumentation path that stops emitting
 # fails the PR lane, not the next person opening Perfetto.
 #
-# Stage 5 — chaos smoke (opt-in, --chaos-smoke): three fixed seeds through
+# Stage 4 — chaos smoke (opt-in, --chaos-smoke): three fixed seeds through
 # the deterministic fault-injection harness (scripts/chaos_sweep.py), so a
 # regression in the recovery ladder fails the PR lane in seconds; the
 # nightly lane runs the full bounded sweep separately.
@@ -38,10 +32,8 @@ cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
 stage="setup"
-smoke_json=""
 smoke_trace=""
 cleanup() {
-    if [[ -n "$smoke_json" ]]; then rm -f "$smoke_json"; fi
     if [[ -n "$smoke_trace" ]]; then rm -f "$smoke_trace"; fi
 }
 on_err() { echo "ci.sh: FAILED during stage: $stage" >&2; }
@@ -106,33 +98,6 @@ PY
 
 stage="pytest"
 python -m pytest -x -q "${PYTEST_ARGS[@]}" "$@"
-
-stage="bench-smoke"
-smoke_json="$(mktemp /tmp/bench_smoke.XXXXXX.json)"
-python -m benchmarks.run --only save_cost,hot_tier,delta,codec,fanout \
-    --sizes small --json "$smoke_json" >/dev/null
-python - "$smoke_json" <<'PY'
-import json
-import sys
-
-doc = json.load(open(sys.argv[1]))
-rows = doc["rows"]
-assert rows, "benchmark smoke produced no rows"
-assert all(r["derived"] != "ERROR" for r in rows), f"benchmark smoke errored: {rows}"
-names = {r["name"] for r in rows}
-assert any(n.startswith("save_parallel_") for n in names), names
-assert any(n.startswith("hot_capture_") for n in names), names
-assert any(n.startswith("delta_save_") for n in names), names
-assert any(n.startswith("chain_restore_") for n in names), names
-assert any(n.startswith("codec_full_save_") for n in names), names
-assert any(n.startswith("codec_delta_save_") for n in names), names
-assert any(n.startswith("codec_restore_") for n in names), names
-assert any(n.startswith("fanout_readers_") for n in names), names
-print(f"bench-smoke: {len(rows)} rows ok")
-PY
-
-stage="bench-compare"
-python scripts/bench_compare.py "$smoke_json" BENCH_checkpointing.json
 
 stage="obs-smoke"
 smoke_trace="$(mktemp /tmp/obs_smoke.XXXXXX.json)"

@@ -51,6 +51,7 @@ import numpy as np
 
 import repro.obs as obs
 from repro.ckpt.manager import CheckpointManager
+from repro.ckpt.policy import CheckpointPolicy
 from repro.ckpt.saver import snapshot_state
 from repro.core import DimSpec, MeshSpec, STATE_KINDS, StateKind, uniform_param_spec
 from repro.core import clock
@@ -222,15 +223,17 @@ class ChaosHarness:
         return CheckpointManager(
             self.root,
             self.plan,
-            keep_last=cfg["keep_last"],
-            save_interval=10,
-            hot_interval=10 if cfg["hot"] else None,
-            disk_interval=10 * cfg["disk_every"] if cfg["hot"] else None,
-            async_save=True,
-            io_workers=1,  # exact serial engine: deterministic hit order
-            save_mode=cfg["save_mode"],
-            full_interval=cfg["full_interval"],
-            registry=self.registry,
+            policy=CheckpointPolicy(
+                keep_last=cfg["keep_last"],
+                save_interval=10,
+                hot_interval=10 if cfg["hot"] else None,
+                disk_interval=10 * cfg["disk_every"] if cfg["hot"] else None,
+                async_save=True,
+                io_workers=1,  # exact serial engine: deterministic hit order
+                save_mode=cfg["save_mode"],
+                full_interval=cfg["full_interval"],
+                registry=self.registry,
+            ),
         )
 
     def _train_state(self, step: int) -> TrainState:

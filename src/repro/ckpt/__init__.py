@@ -1,4 +1,9 @@
-from .engine import CheckpointEngine, FragmentIndex, HandleCache, default_engine
+from repro.core.engine import (
+    CheckpointEngine,
+    FragmentIndex,
+    HandleCache,
+    default_engine,
+)
 from .manager import CheckpointManager, RestoreInfo
 from .policy import CheckpointPolicy
 from .restore import (

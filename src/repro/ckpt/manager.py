@@ -50,7 +50,7 @@ from repro.core.plan import ResumeMode, TargetSpec, plan_resume, stream_transfor
 from repro.core.tensor_io import IntegrityError
 from repro.dist.sharding import ShardingPlan
 from repro.train.optimizer import TrainState
-from .policy import CheckpointPolicy, policy_from_legacy_kwargs
+from .policy import CheckpointPolicy
 from .restore import (
     RestoreStats,
     params_from_source,
@@ -98,7 +98,6 @@ class CheckpointManager:
         *,
         policy: CheckpointPolicy | None = None,
         config_fingerprint: Mapping[str, Any] | None = None,
-        **legacy,
     ):
         """All checkpointing knobs live on one validated
         :class:`~repro.ckpt.policy.CheckpointPolicy` — cadence, retention,
@@ -107,13 +106,6 @@ class CheckpointManager:
         ``config_fingerprint`` stays a separate argument: it is this
         *run's* identity (model/parallelism fingerprints recorded into
         every manifest), not checkpointing policy.
-
-        Legacy spelling: the individual keyword arguments the manager took
-        before ``CheckpointPolicy`` existed (``keep_last=...``,
-        ``save_mode=...``, ``hot_interval=...``, …) still work — they are
-        mapped onto a policy with a ``DeprecationWarning``.  Mixing
-        ``policy=`` with legacy knobs is an error (two sources of truth),
-        as is any keyword that never was a knob.
 
         Hot-tier policy: ``hot_interval`` (None = disabled) captures a
         peer-replicated in-memory snapshot every N steps; every
@@ -145,15 +137,6 @@ class CheckpointManager:
         observed).  The newest committed step is always within
         ``keep_last``, so a publication's disk fallback tier outlives GC.
         """
-        if legacy:
-            if policy is not None:
-                raise TypeError(
-                    "pass either policy=CheckpointPolicy(...) or individual "
-                    f"legacy knobs, not both (got {sorted(legacy)})"
-                )
-            policy = policy_from_legacy_kwargs(
-                legacy, where="CheckpointManager"
-            )
         self.policy = policy if policy is not None else CheckpointPolicy()
         policy = self.policy
         self.root = Path(root)

@@ -9,21 +9,15 @@ validate once in ``__post_init__``, and hand the same object to
 :class:`~repro.ckpt.manager.CheckpointManager`,
 :meth:`~repro.train.trainer.Trainer.create`, or build it from
 ``launch/train.py`` flags.
-
-Old call sites keep working: the manager and Trainer map legacy keyword
-arguments onto a policy through :func:`policy_from_legacy_kwargs` (with a
-``DeprecationWarning``), so the shim is one code path, tested in
-``tests/test_policy.py``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import warnings
 
 from repro.core.codec import CodecPolicy
 
-__all__ = ["CheckpointPolicy", "LEGACY_KNOBS", "policy_from_legacy_kwargs"]
+__all__ = ["CheckpointPolicy"]
 
 
 @dataclasses.dataclass
@@ -119,31 +113,3 @@ class CheckpointPolicy:
             if self.disk_interval is not None
             else self.save_interval
         )
-
-
-# Keyword arguments the deprecation shim accepts — exactly the knobs the
-# manager/Trainer took individually before CheckpointPolicy existed.
-LEGACY_KNOBS = frozenset(f.name for f in dataclasses.fields(CheckpointPolicy))
-
-
-def policy_from_legacy_kwargs(
-    legacy: dict, *, where: str, stacklevel: int = 3
-) -> CheckpointPolicy:
-    """Map pre-policy keyword arguments onto a :class:`CheckpointPolicy`.
-
-    Raises ``TypeError`` on names that never were knobs (typos must not be
-    silently swallowed just because a shim exists) and warns once per call
-    site that the spelling is deprecated."""
-    unknown = set(legacy) - LEGACY_KNOBS
-    if unknown:
-        raise TypeError(
-            f"{where}: unexpected keyword arguments {sorted(unknown)}"
-        )
-    warnings.warn(
-        f"{where}: passing individual checkpoint knobs "
-        f"({', '.join(sorted(legacy))}) is deprecated; "
-        "pass policy=CheckpointPolicy(...) instead",
-        DeprecationWarning,
-        stacklevel=stacklevel,
-    )
-    return CheckpointPolicy(**legacy)

@@ -14,8 +14,8 @@ exactly those bytes —
 ``read_region_from_source`` additionally supports serving an arbitrary
 region from any *fragment source* by unioning overlapping fragments on the
 fly — a distributed checkpoint on disk (the beyond-paper "direct reshard"
-fast path benchmarked in ``benchmarks/bench_checkpointing.py``, skipping
-atom materialization when the Source can stream straight into the Target)
+fast path, skipping atom materialization when the Source can stream
+straight into the Target)
 or an in-memory hot snapshot (``repro.hot``: the ``HOT_RESHARD`` recovery
 tier unions surviving peer replicas without touching disk).  The two share
 one code path because the engine's index and fragment reads are generic

@@ -15,8 +15,9 @@ Parallelism: Union is independent per parameter (paper: "can execute in
 parallel at individual parameter level; more parallelism leads to faster
 speed but is also more memory intensive"), so the driver fans out over a
 thread pool — the work is mmap reads + memcpy, which release the GIL.
-``streaming=True`` unions directly into a memory-mapped atom file, making
-peak working memory O(largest shard) instead of O(largest parameter).
+A parameter that needs no padding-strip or averaging unions directly into
+a memory-mapped atom file, making peak working memory O(largest shard)
+instead of O(largest parameter).
 """
 
 from __future__ import annotations
@@ -108,7 +109,6 @@ def _convert_one(
     ckpt: DistCheckpoint,
     ucp: UcpCheckpoint,
     spec: ParamSpec,
-    streaming: bool,
     engine: CheckpointEngine | None = None,
 ) -> tuple[int, int, int, dict[StateKind, str]]:
     """Union + StripPadding + Save for one parameter (all state kinds).
@@ -119,7 +119,7 @@ def _convert_one(
     manifest (verified by ``UcpCheckpoint.validate``).
     """
     with obs.span("convert.param", param=spec.name) as sp:
-        result = _convert_one_traced(ckpt, ucp, spec, streaming, engine)
+        result = _convert_one_traced(ckpt, ucp, spec, engine)
         sp.set(bytes_written=result[1], atoms=result[2])
     return result
 
@@ -128,7 +128,6 @@ def _convert_one_traced(
     ckpt: DistCheckpoint,
     ucp: UcpCheckpoint,
     spec: ParamSpec,
-    streaming: bool,
     engine: CheckpointEngine | None = None,
 ) -> tuple[int, int, int, dict[StateKind, str]]:
     read = written = atoms = 0
@@ -137,12 +136,7 @@ def _convert_one_traced(
         if kind not in spec.states:
             continue
         dtype = resolve_dtype(spec.states[kind].dtype)
-        can_stream = (
-            streaming
-            and not spec.average
-            and tuple(spec.runtime_shape) == tuple(spec.logical_shape)
-        )
-        if can_stream:
+        if not spec.average and tuple(spec.runtime_shape) == tuple(spec.logical_shape):
             out = ucp.create_atom_memmap(
                 spec.name, kind, tuple(spec.logical_shape), spec.states[kind].dtype
             )
@@ -165,7 +159,6 @@ def convert_to_ucp(
     *,
     names: Sequence[str] | None = None,
     workers: int | None = None,
-    streaming: bool = True,
     engine: CheckpointEngine | None = None,
 ) -> tuple[UcpCheckpoint, ConvertStats]:
     """Convert a committed distributed checkpoint into a UCP atom checkpoint.
@@ -227,7 +220,7 @@ def convert_to_ucp(
         try:
             specs = list(todo.values())
             results = engine.map(
-                lambda s: _convert_one(ckpt, ucp, s, streaming, engine), specs
+                lambda s: _convert_one(ckpt, ucp, s, engine), specs
             )
         finally:
             if owns_engine:

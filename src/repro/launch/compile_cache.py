@@ -10,7 +10,7 @@ is never derived from a temporary name, a process id or the time:
 * unset — the cache lives at ``<checkout>/.jax_cache`` (git-ignored).
 
 Every entry point (``repro.launch.train``, ``repro.launch.serve``,
-``chip_smoke.py``, ``benchmarks.run``) calls :func:`enable_compile_cache`
+``chip_smoke.py``, ``chipbench/``) calls :func:`enable_compile_cache`
 before its first JAX computation.
 """
 

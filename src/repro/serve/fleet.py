@@ -24,7 +24,8 @@ target layout) pair is built single-flight in the engine's atom cache and
 every co-hosted replica's tree references the same arrays — N replica
 threads on one serving host cost one restore's work plus N cheap tree
 constructions, which is what makes fleet restore bandwidth scale with N
-(see ``benchmarks/bench_fanout.py``) instead of dividing by it.
+instead of dividing by it (``tests/test_fanout.py`` pins the O(1) disk
+reads).
 """
 
 from __future__ import annotations

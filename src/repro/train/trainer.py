@@ -3,9 +3,11 @@
 The resume path realizes the paper's workflow: on start-up the trainer asks
 the CheckpointManager for the latest committed checkpoint; if the current
 (mesh, parallelism, precision) equals the Source's, state streams back via
-DIRECT per-rank reads; otherwise the manager converts to UCP atoms once and
-Loads them under the new Target — training continues at the checkpointed
-step with the same global data order (reshard-invariant pipeline).
+DIRECT per-rank reads; otherwise the manager streams Source fragments
+straight into the new Target layout (RESHARD_STREAM, with converting to UCP
+atoms and Loading them as the fallback) — training continues at the
+checkpointed step with the same global data order (reshard-invariant
+pipeline).
 """
 
 from __future__ import annotations
@@ -26,7 +28,7 @@ from repro.configs.base import (
 )
 from repro.core.layout import MeshSpec
 from repro.ckpt.manager import CheckpointManager, RestoreInfo
-from repro.ckpt.policy import CheckpointPolicy, policy_from_legacy_kwargs
+from repro.ckpt.policy import CheckpointPolicy
 from repro.dist.sharding import ShardingPlan, make_plan, make_sharder, vocab_multiple
 from repro.models import build_model
 from repro.models.lm import LM
@@ -65,20 +67,10 @@ class Trainer:
         ckpt_dir: str | None = None,
         policy: CheckpointPolicy | None = None,
         grad_transform=None,
-        **legacy,
     ) -> "Trainer":
         """Checkpointing is configured by one
-        :class:`~repro.ckpt.policy.CheckpointPolicy` (``policy=``).  The
-        pre-policy keyword spelling (``keep_last=``, ``save_interval=``,
-        ``hot_interval=``, …) still works via a deprecation shim; mixing
-        both is a ``TypeError``."""
-        if legacy:
-            if policy is not None:
-                raise TypeError(
-                    "pass either policy=CheckpointPolicy(...) or individual "
-                    f"legacy knobs, not both (got {sorted(legacy)})"
-                )
-            policy = policy_from_legacy_kwargs(legacy, where="Trainer.create")
+        :class:`~repro.ckpt.policy.CheckpointPolicy` (``policy=``; None
+        means the policy's defaults)."""
         mesh_spec = MeshSpec.from_mesh(jmesh)
         lm = build_model(
             cfg,

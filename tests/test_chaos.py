@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 from repro.ckpt.manager import CheckpointManager
+from repro.ckpt.policy import CheckpointPolicy
 from repro.ckpt.saver import AsyncSaver, snapshot_state, write_distributed
 from repro.core import (
     DimSpec,
@@ -183,8 +184,8 @@ def test_controller_counts_hits_from_arming():
 
 def test_invariants_clean_manager_passes(setup):
     tmp, plan, tgt_plan, jmesh = setup
-    mgr = CheckpointManager(tmp / "ckpt", plan, keep_last=2, save_interval=10,
-                            async_save=False, io_workers=1)
+    mgr = CheckpointManager(tmp / "ckpt", plan, policy=CheckpointPolicy(
+        keep_last=2, save_interval=10, async_save=False, io_workers=1))
     snap = _random_state(plan.param_specs)
     mgr.save(_train_state(snap, 10), 10)
     assert check_invariants(mgr) == []
@@ -193,8 +194,8 @@ def test_invariants_clean_manager_passes(setup):
 
 def test_invariants_flag_torn_checkpoint(setup):
     tmp, plan, tgt_plan, jmesh = setup
-    mgr = CheckpointManager(tmp / "ckpt", plan, keep_last=2, save_interval=10,
-                            async_save=False, io_workers=1)
+    mgr = CheckpointManager(tmp / "ckpt", plan, policy=CheckpointPolicy(
+        keep_last=2, save_interval=10, async_save=False, io_workers=1))
     snap = _random_state(plan.param_specs)
     mgr.save(_train_state(snap, 10), 10)
     # Tear it: a shard file vanishes after commit.
@@ -222,8 +223,8 @@ def test_diff_snapshots_is_bit_exact():
 
 def test_async_save_crash_surfaces_on_wait(setup):
     tmp, plan, tgt_plan, jmesh = setup
-    mgr = CheckpointManager(tmp / "ckpt", plan, keep_last=2, save_interval=10,
-                            async_save=True, io_workers=1)
+    mgr = CheckpointManager(tmp / "ckpt", plan, policy=CheckpointPolicy(
+        keep_last=2, save_interval=10, async_save=True, io_workers=1))
     snap = _random_state(plan.param_specs)
     sched = Schedule(0, (FaultSpec("saver.shard", action="crash", hit=1),))
     with ChaosController(sched):
@@ -237,8 +238,8 @@ def test_async_save_crash_surfaces_on_wait(setup):
 
 def test_async_save_crash_surfaces_on_close(setup):
     tmp, plan, tgt_plan, jmesh = setup
-    mgr = CheckpointManager(tmp / "ckpt", plan, keep_last=2, save_interval=10,
-                            async_save=True, io_workers=1)
+    mgr = CheckpointManager(tmp / "ckpt", plan, policy=CheckpointPolicy(
+        keep_last=2, save_interval=10, async_save=True, io_workers=1))
     snap = _random_state(plan.param_specs)
     sched = Schedule(0, (FaultSpec("saver.pre_commit", action="crash", hit=1),))
     with ChaosController(sched):
@@ -250,9 +251,9 @@ def test_async_save_crash_surfaces_on_close(setup):
 
 def test_drain_crash_surfaces_on_wait(setup):
     tmp, plan, tgt_plan, jmesh = setup
-    mgr = CheckpointManager(tmp / "ckpt", plan, keep_last=2, save_interval=10,
-                            hot_interval=10, disk_interval=10,
-                            async_save=True, io_workers=1)
+    mgr = CheckpointManager(tmp / "ckpt", plan, policy=CheckpointPolicy(
+        keep_last=2, save_interval=10, hot_interval=10, disk_interval=10,
+        async_save=True, io_workers=1))
     snap = _random_state(plan.param_specs)
     sched = Schedule(0, (FaultSpec("drain.shard", action="crash", hit=1),))
     with ChaosController(sched):
@@ -276,8 +277,8 @@ def _paused_mid_save(tmp, plan, snap):
     """Start an async save of step 10 and park its writer thread mid-shards
     (pause gate), returning (mgr, controller ctx).  Caller drives the race
     while the writer is frozen between 'some shards written' and COMMIT."""
-    mgr = CheckpointManager(tmp / "ckpt", plan, keep_last=2, save_interval=10,
-                            async_save=True, io_workers=1)
+    mgr = CheckpointManager(tmp / "ckpt", plan, policy=CheckpointPolicy(
+        keep_last=2, save_interval=10, async_save=True, io_workers=1))
     sched = Schedule(0, (FaultSpec("saver.shard", action="pause",
                                    hit=10, args=("mid-save",)),))
     ctrl = ChaosController(sched)
@@ -333,9 +334,9 @@ def test_replay_gc_vs_inflight_save_fails_without_fix(setup, monkeypatch):
 def _paused_mid_delta(tmp, plan, snap):
     """Commit a full step 10, then freeze an async *delta* save of step 20
     right after its base (step 10) was resolved but before any shard write."""
-    mgr = CheckpointManager(tmp / "ckpt", plan, keep_last=1, save_interval=10,
-                            async_save=True, io_workers=1,
-                            save_mode="delta", full_interval=8)
+    mgr = CheckpointManager(tmp / "ckpt", plan, policy=CheckpointPolicy(
+        keep_last=1, save_interval=10, async_save=True, io_workers=1, save_mode="delta",
+        full_interval=8))
     mgr.save(_train_state(snap, 10), 10, block=True)  # seq 0: forced full
     _mutate(snap, 1)
     sched = Schedule(0, (FaultSpec("saver.shard", action="pause",
@@ -412,9 +413,9 @@ def test_gc_crash_mid_loop_deletes_newest_first(setup):
     order is newest-first (found by chaos seed 23)."""
     tmp, plan, tgt_plan, jmesh = setup
     snap = _random_state(plan.param_specs)
-    mgr = CheckpointManager(tmp / "ckpt", plan, keep_last=1, save_interval=10,
-                            async_save=False, io_workers=1,
-                            save_mode="delta", full_interval=3)
+    mgr = CheckpointManager(tmp / "ckpt", plan, policy=CheckpointPolicy(
+        keep_last=1, save_interval=10, async_save=False, io_workers=1,
+        save_mode="delta", full_interval=3))
     # seq 0 full(10) <- delta(20) <- delta(30); seq 3 full(40) rebases, so
     # GC then wants the whole old chain {10, 20, 30}.
     for step in (10, 20, 30):
@@ -439,8 +440,9 @@ def test_published_step_outlives_keep_last(setup):
     tmp, plan, tgt_plan, jmesh = setup
     snap = _random_state(plan.param_specs)
     registry = PublicationRegistry()
-    mgr = CheckpointManager(tmp / "ckpt", plan, keep_last=1, save_interval=10,
-                            async_save=False, io_workers=1, registry=registry)
+    mgr = CheckpointManager(tmp / "ckpt", plan, policy=CheckpointPolicy(
+        keep_last=1, save_interval=10, async_save=False, io_workers=1,
+        registry=registry))
     mgr.save(_train_state(snap, 10), 10)  # publishes step 10
     ref10 = {n: kv[StateKind.FP32].copy() for n, kv in snap.items()}
     assert registry.current().step == 10
@@ -494,8 +496,8 @@ def test_clock_skew_cannot_change_gc_newest(setup):
     commit stamp says 'two hours ago' is still the newest if its step is."""
     tmp, plan, tgt_plan, jmesh = setup
     snap = _random_state(plan.param_specs)
-    mgr = CheckpointManager(tmp / "ckpt", plan, keep_last=1, save_interval=10,
-                            async_save=False, io_workers=1)
+    mgr = CheckpointManager(tmp / "ckpt", plan, policy=CheckpointPolicy(
+        keep_last=1, save_interval=10, async_save=False, io_workers=1))
     try:
         mgr.save(_train_state(snap, 10), 10)
         clock.skew(-7200)  # step 20's stamps now predate step 10's

@@ -14,6 +14,7 @@ from repro.configs import ParallelismConfig, get_config, reduced
 from repro.core.layout import MeshSpec
 from repro.core.plan import ResumeMode
 from repro.ckpt.manager import CheckpointManager
+from repro.ckpt.policy import CheckpointPolicy
 from repro.ckpt.saver import AsyncSaver, snapshot_state, write_distributed
 from repro.dist.sharding import make_plan, vocab_multiple
 from repro.launch.mesh import make_mesh
@@ -41,7 +42,7 @@ def _state_equal(a, b):
 
 def test_sync_save_restore_roundtrip(setup):
     tmp, cfg, lm, plan, state, jmesh = setup
-    mgr = CheckpointManager(tmp / "ck", plan, async_save=False)
+    mgr = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(async_save=False))
     mgr.save(state, 10)
     assert mgr.latest_step() == 10
     restored, info = mgr.restore(jmesh)
@@ -52,9 +53,11 @@ def test_sync_save_restore_roundtrip(setup):
 
 def test_async_save_equals_sync(setup):
     tmp, cfg, lm, plan, state, jmesh = setup
-    m1 = CheckpointManager(tmp / "sync", plan, async_save=False)
+    m1 = CheckpointManager(tmp / "sync", plan, policy=CheckpointPolicy(
+        async_save=False))
     m1.save(state, 5)
-    m2 = CheckpointManager(tmp / "async", plan, async_save=True)
+    m2 = CheckpointManager(tmp / "async", plan, policy=CheckpointPolicy(
+        async_save=True))
     m2.save(state, 5)
     results = m2.wait()
     assert results and results[0].step == 5
@@ -71,7 +74,8 @@ def test_async_save_equals_sync(setup):
 
 def test_keep_last_gc(setup):
     tmp, cfg, lm, plan, state, jmesh = setup
-    mgr = CheckpointManager(tmp / "ck", plan, keep_last=2, async_save=False)
+    mgr = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(
+        keep_last=2, async_save=False))
     for s in (10, 20, 30, 40):
         mgr.save(state, s)
     assert mgr.steps() == [30, 40]
@@ -80,7 +84,7 @@ def test_keep_last_gc(setup):
 
 def test_uncommitted_checkpoints_ignored_and_cleaned(setup):
     tmp, cfg, lm, plan, state, jmesh = setup
-    mgr = CheckpointManager(tmp / "ck", plan, async_save=False)
+    mgr = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(async_save=False))
     mgr.save(state, 10)
     # simulate crash-during-save: newer dir without COMMIT
     crashed = mgr.step_dir(20)
@@ -93,7 +97,8 @@ def test_uncommitted_checkpoints_ignored_and_cleaned(setup):
 
 def test_restore_prefers_requested_step(setup):
     tmp, cfg, lm, plan, state, jmesh = setup
-    mgr = CheckpointManager(tmp / "ck", plan, keep_last=10, async_save=False)
+    mgr = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(
+        keep_last=10, async_save=False))
     mgr.save(state, 10)
     mgr.save(state, 20)
     _, info = mgr.restore(jmesh, step=10)
@@ -102,7 +107,7 @@ def test_restore_prefers_requested_step(setup):
 
 def test_reshard_stream_restore_writes_nothing(setup):
     tmp, cfg, lm, plan, state, jmesh = setup
-    mgr = CheckpointManager(tmp / "ck", plan, async_save=False)
+    mgr = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(async_save=False))
     mgr.save(state, 10)
     # target: different parallelism flags → structurally different layouts
     parallel2 = ParallelismConfig(zero=1, fsdp=False)
@@ -125,12 +130,14 @@ def test_restore_params_reads_weights_only(setup, zero1):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     tmp, cfg, lm, plan, state, jmesh = setup
-    CheckpointManager(tmp / "ck", plan, async_save=False).save(state, 10)
+    CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(
+        async_save=False)).save(state, 10)
     if zero1:
         parallel2 = ParallelismConfig(zero=1, fsdp=False)
         lm2 = build_model(cfg, vocab_multiple=vocab_multiple(parallel2, plan.mesh))
         plan = make_plan(cfg, lm2.registry, parallel2, plan.mesh)
-    reader = CheckpointManager(tmp / "ck", plan, async_save=False)
+    reader = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(
+        async_save=False))
     params, info = reader.restore_params(jmesh)
     full, full_info = reader.restore(jmesh)
     want = ResumeMode.RESHARD_STREAM if zero1 else ResumeMode.DIRECT
@@ -144,7 +151,7 @@ def test_restore_params_reads_weights_only(setup, zero1):
 
 def test_via_ucp_restore_and_conversion_cache(setup):
     tmp, cfg, lm, plan, state, jmesh = setup
-    mgr = CheckpointManager(tmp / "ck", plan, async_save=False)
+    mgr = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(async_save=False))
     mgr.save(state, 10)
     parallel2 = ParallelismConfig(zero=1, fsdp=False)
     mesh2 = MeshSpec.from_dict({"data": 1, "model": 1})
@@ -167,7 +174,7 @@ def test_via_ucp_restore_and_conversion_cache(setup):
 
 def test_export_ucp_is_explicit_and_cached(setup):
     tmp, cfg, lm, plan, state, jmesh = setup
-    mgr = CheckpointManager(tmp / "ck", plan, async_save=False)
+    mgr = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(async_save=False))
     mgr.save(state, 10)
     ucp, cstats = mgr.export_ucp()
     assert cstats is not None and cstats.params > 0
@@ -204,7 +211,7 @@ def test_gc_spares_inflight_save_dirs(setup, monkeypatch):
         return real_write(snap, plan_, step, root, **kw)
 
     monkeypatch.setattr(saver_mod, "write_distributed", stalled_write)
-    mgr = CheckpointManager(tmp / "ck", plan, async_save=True)
+    mgr = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(async_save=True))
     mgr.save(state, 10)  # queued; stalls with its directory half-written
     assert started.wait(20)
     # a newer blocking save commits first, then gc() runs: step_10 is

@@ -15,14 +15,16 @@ Covers the DESIGN.md §10 contract:
   working (a coded save still inherits unchanged shards);
 * every consumer above the single decode point serves coded checkpoints
   unchanged: DIRECT restore, streaming reshard, the delta chain, the hot
-  drain's promoted steps, and the peer fan-out.
+  drain's promoted steps, and the peer fan-out;
+* a resume from block-quantized optimizer moments tracks the uninterrupted
+  loss curve within the paper's reconfiguration tolerance (0.02).
 """
 
 import jax
 import numpy as np
 import pytest
 
-from repro.configs import ParallelismConfig, get_config, reduced
+from repro.configs import ParallelismConfig, TrainConfig, get_config, reduced
 from repro.core import (
     DimSpec,
     DistCheckpoint,
@@ -58,6 +60,7 @@ from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.serve import PeerFragmentSource, PublicationRegistry
 from repro.train.optimizer import TrainState, init_state
+from repro.train.trainer import Trainer
 
 MESH_2X2 = MeshSpec.from_dict({"data": 2, "model": 2})
 MESH_1X1 = MeshSpec.from_dict({"data": 1, "model": 1})
@@ -527,3 +530,55 @@ def test_hot_ladder_promotes_coded_deltas(model_setup):
                     jax.tree.leaves(states[3].params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     mgr.close()
+
+
+# ---------------------------------------------------------------------------
+# Loss-curve equivalence of a resume from coded moments
+# ---------------------------------------------------------------------------
+
+EQUIV_TOL = 0.02  # max |dloss|, the paper's reconfiguration tolerance
+
+
+def _equiv_trainer(ckpt_dir, *, save_interval=8, codec=None):
+    return Trainer.create(
+        reduced(get_config("smollm-360m")), ParallelismConfig(),
+        TrainConfig(warmup_steps=2, total_steps=100),
+        make_mesh((1, 1), ("data", "model")), batch_size=4, seq_len=24,
+        ckpt_dir=str(ckpt_dir),
+        policy=CheckpointPolicy(
+            save_interval=save_interval, async_save=False, codec=codec,
+        ),
+    )
+
+
+@pytest.fixture(scope="module")
+def baseline_losses(tmp_path_factory):
+    """The uninterrupted 16-step run: step -> loss."""
+    t = _equiv_trainer(tmp_path_factory.mktemp("base"))
+    state, _ = t.init_or_restore()
+    _, hist = t.run(state, 0, 16)
+    t.manager.close()
+    return {h["step"]: h["loss"] for h in hist}
+
+
+@pytest.mark.parametrize(
+    "codec",
+    [None, "int8:b256", "fp8:e4m3:b256"],
+    ids=["lossless", "int8_moments", "fp8_moments"],
+)
+def test_coded_moments_resume_tracks_baseline(baseline_losses, tmp_path, codec):
+    """Train 8 steps and save with the moments coded, resume in a fresh
+    trainer and train 8 more: every resumed loss stays within
+    ``EQUIV_TOL`` of the uninterrupted run's."""
+    t1 = _equiv_trainer(tmp_path, codec=codec)
+    s1, _ = t1.init_or_restore()
+    t1.run(s1, 0, 8)
+    t1.manager.close()
+    # the resumed run must not save, or it would resume from its own step
+    t2 = _equiv_trainer(tmp_path, save_interval=10**6, codec=codec)
+    s2, info = t2.init_or_restore()
+    assert info is not None and info.step == 8
+    _, hist = t2.run(s2, 8, 8)
+    t2.manager.close()
+    delta = max(abs(h["loss"] - baseline_losses[h["step"]]) for h in hist)
+    assert delta <= EQUIV_TOL, f"codec {codec}: resumed loss off by {delta:.4f}"

@@ -27,6 +27,7 @@ from repro.core.layout import MeshSpec
 from repro.core.plan import ResumeMode
 from repro.core.pytree import flatten_with_paths, unflatten_from_paths
 from repro.ckpt.manager import CheckpointManager
+from repro.ckpt.policy import CheckpointPolicy
 from repro.ckpt.saver import snapshot_state, write_distributed
 from repro.dist.sharding import make_plan, vocab_multiple
 from repro.launch.mesh import make_mesh
@@ -72,10 +73,8 @@ def _reshard_plan(cfg):
 
 def test_delta_save_writes_only_changed_shards(setup):
     tmp, cfg, plan, state, jmesh = setup
-    mgr = CheckpointManager(
-        tmp / "ck", plan, async_save=False, save_mode="delta",
-        full_interval=100, keep_last=100,
-    )
+    mgr = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(
+        async_save=False, save_mode="delta", full_interval=100, keep_last=100))
     mgr.save(state, 10)  # seq 0: forced full rebase
     state2 = _bump(state, 0)
     mgr.save(state2, 20)
@@ -104,10 +103,8 @@ def test_chain_restore_bit_identical_to_full(setup, depth):
     """Restore from a K-deep chain — DIRECT and RESHARD_STREAM — matches a
     full save of the same final state, bit for bit."""
     tmp, cfg, plan, state, jmesh = setup
-    mgr = CheckpointManager(
-        tmp / "delta", plan, async_save=False, save_mode="delta",
-        full_interval=100, keep_last=100,
-    )
+    mgr = CheckpointManager(tmp / "delta", plan, policy=CheckpointPolicy(
+        async_save=False, save_mode="delta", full_interval=100, keep_last=100))
     s = state
     mgr.save(s, 10)
     for i in range(depth):
@@ -117,7 +114,8 @@ def test_chain_restore_bit_identical_to_full(setup, depth):
     ck = DistCheckpoint.open(mgr.step_dir(tip))
     assert ck.manifest.base_step is not None  # really a delta
     # equivalent full save of the same final state
-    full = CheckpointManager(tmp / "full", plan, async_save=False)
+    full = CheckpointManager(tmp / "full", plan, policy=CheckpointPolicy(
+        async_save=False))
     full.save(s, tip)
 
     r_delta, info = mgr.restore(jmesh, step=tip)
@@ -147,11 +145,9 @@ def test_hot_drainer_promotes_deltas(setup):
     """Hot-tier promotion follows the same delta policy: the drained disk
     steps form a chain and restore bit-identically."""
     tmp, cfg, plan, state, jmesh = setup
-    mgr = CheckpointManager(
-        tmp / "ck", plan, save_mode="delta", full_interval=100,
-        keep_last=100, hot_interval=1, disk_interval=1,
-        hot_max_snapshots=2, async_save=False,
-    )
+    mgr = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(
+        save_mode="delta", full_interval=100, keep_last=100, hot_interval=1,
+        disk_interval=1, hot_max_snapshots=2, async_save=False))
     s = state
     states = {}
     for i, step in enumerate((1, 2, 3)):
@@ -176,10 +172,8 @@ def test_hot_drainer_promotes_deltas(setup):
 
 def test_crash_mid_delta_leaves_chain_servable(setup):
     tmp, cfg, plan, state, jmesh = setup
-    mgr = CheckpointManager(
-        tmp / "ck", plan, async_save=False, save_mode="delta",
-        full_interval=100, keep_last=100,
-    )
+    mgr = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(
+        async_save=False, save_mode="delta", full_interval=100, keep_last=100))
     mgr.save(state, 10)
     state2 = _bump(state, 0)
     mgr.save(state2, 20)
@@ -207,10 +201,8 @@ def test_crash_mid_delta_leaves_chain_servable(setup):
 
 def test_gc_keeps_referenced_bases_until_rebase(setup):
     tmp, cfg, plan, state, jmesh = setup
-    mgr = CheckpointManager(
-        tmp / "ck", plan, async_save=False, save_mode="delta",
-        full_interval=100, keep_last=1,
-    )
+    mgr = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(
+        async_save=False, save_mode="delta", full_interval=100, keep_last=1))
     s = state
     mgr.save(s, 10)  # full base
     for i, step in enumerate((20, 30)):
@@ -260,10 +252,8 @@ def test_gc_pins_inflight_delta_base(setup, monkeypatch):
         return real(snap, plan_, step, root, **kw)
 
     monkeypatch.setattr(saver_mod, "write_distributed", stalled)
-    mgr = CheckpointManager(
-        tmp / "ck", plan, async_save=True, save_mode="delta",
-        full_interval=2, keep_last=1,
-    )
+    mgr = CheckpointManager(tmp / "ck", plan, policy=CheckpointPolicy(
+        async_save=True, save_mode="delta", full_interval=2, keep_last=1))
     mgr.save(state, 10, block=True)  # seq 0: full (the future delta base)
     state2 = _bump(state, 0)
     mgr.save(state2, 30)  # seq 1: delta, queued, stalls post-resolution
@@ -333,15 +323,15 @@ def test_hot_promotion_honors_save_mode_all(setup):
     """save_mode='all' with the hot tier must capture and promote the full
     per-replica write set, not silently degrade to dedup."""
     tmp, cfg, plan, state, jmesh = setup
-    mgr_all = CheckpointManager(
-        tmp / "all", plan, save_mode="all", hot_interval=1, disk_interval=1,
-        async_save=False, keep_last=10,
-    )
+    mgr_all = CheckpointManager(tmp / "all", plan, policy=CheckpointPolicy(
+        save_mode="all", hot_interval=1, disk_interval=1, async_save=False,
+        keep_last=10))
     mgr_all.save(state, 1, block=True)
     mgr_all.wait()
     ck = DistCheckpoint.open(mgr_all.step_dir(1))
     assert ck.manifest.save_mode == "all"
-    mgr_ded = CheckpointManager(tmp / "ded", plan, async_save=False)
+    mgr_ded = CheckpointManager(tmp / "ded", plan, policy=CheckpointPolicy(
+        async_save=False))
     mgr_ded.save(state, 1)
     n_all = len(list(mgr_all.step_dir(1).rglob("*.npy")))
     n_ded = len(list(mgr_ded.step_dir(1).rglob("*.npy")))

@@ -19,6 +19,7 @@ from repro.core import (
     uniform_param_spec,
 )
 from repro.core.engine import CheckpointEngine
+from repro.ckpt.policy import CheckpointPolicy
 from repro.ckpt.restore import (
     params_from_source,
     read_region_from_source,
@@ -387,8 +388,8 @@ def test_manager_publishes_on_commit(tmp_path):
     registry = PublicationRegistry()
     sub = registry.subscribe("watcher")
     # sync saves publish immediately
-    mgr = CheckpointManager(tmp_path / "ck", plan, async_save=False,
-                            registry=registry)
+    mgr = CheckpointManager(tmp_path / "ck", plan, policy=CheckpointPolicy(
+        async_save=False, registry=registry))
     mgr.save(state, 10)
     pubs = sub.poll()
     assert [p.step for p in pubs] == [10]
@@ -396,8 +397,8 @@ def test_manager_publishes_on_commit(tmp_path):
     # manager attached to an existing root first re-announces the step that
     # is already committed (its publish cursor starts empty) — an idempotent
     # empty-diff delta for any subscriber that already saw it.
-    mgr2 = CheckpointManager(tmp_path / "ck", plan, async_save=True,
-                             registry=registry)
+    mgr2 = CheckpointManager(tmp_path / "ck", plan, policy=CheckpointPolicy(
+        async_save=True, registry=registry))
     mgr2.save(state, 20)
     mgr2.wait()
     mgr2.close()
@@ -436,9 +437,9 @@ def test_crash_mid_publish_fleet_still_serves(tmp_path):
         )
 
     registry = PublicationRegistry()
-    mgr = CheckpointManager(tmp_path / "ck", plan, keep_last=1,
-                            save_interval=10, async_save=False, io_workers=1,
-                            registry=registry)
+    mgr = CheckpointManager(tmp_path / "ck", plan, policy=CheckpointPolicy(
+        keep_last=1, save_interval=10, async_save=False, io_workers=1,
+        registry=registry))
     snap10, state10 = state_at(1)
     mgr.save(state10, 10)
     r1 = FleetReplica("r1", registry, tgt_plan, jmesh)

@@ -16,6 +16,7 @@ from repro.core import (
     content_digest,
     uniform_param_spec,
 )
+from repro.ckpt.policy import CheckpointPolicy
 from repro.core.plan import ResumeMode, TargetSpec
 from repro.dist.sharding import ShardingPlan
 from repro.hot import (
@@ -362,7 +363,8 @@ def test_restore_verify_flag_raises_on_corruption(tmp_path):
     state = init_state(lm.init(jax.random.PRNGKey(0)))
     jmesh = make_mesh((1, 1), ("data", "model"))
 
-    mgr = CheckpointManager(tmp_path / "ck", plan, async_save=False)
+    mgr = CheckpointManager(tmp_path / "ck", plan, policy=CheckpointPolicy(
+        async_save=False))
     mgr.save(state, 10)
     mgr.restore(jmesh, verify=True)  # clean checkpoint verifies fine
     victim = next(iter(sorted((tmp_path / "ck").glob("step_*/ranks/**/*.npy"))))
@@ -447,9 +449,8 @@ def test_crash_mid_save_discovery_hot_recovery_and_gc(tmp_path, monkeypatch):
     state = init_state(lm.init(jax.random.PRNGKey(0)))
     jmesh = make_mesh((1, 1), ("data", "model"))
 
-    mgr = CheckpointManager(
-        tmp_path / "ck", plan, hot_interval=5, save_interval=5, async_save=False
-    )
+    mgr = CheckpointManager(tmp_path / "ck", plan, policy=CheckpointPolicy(
+        hot_interval=5, save_interval=5, async_save=False))
     mgr.save(state, 5)  # committed disk checkpoint via drain
     mgr.wait()
     assert mgr.latest_step() == 5

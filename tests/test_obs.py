@@ -32,6 +32,7 @@ from repro.core.dist_ckpt import DistCheckpoint
 from repro.core.layout import MeshSpec
 from repro.core.pytree import flatten_with_paths, unflatten_from_paths
 from repro.ckpt.manager import CheckpointManager
+from repro.ckpt.policy import CheckpointPolicy
 from repro.ckpt.saver import snapshot_state, write_distributed
 from repro.dist.sharding import make_plan, vocab_multiple
 from repro.models import build_model
@@ -218,9 +219,8 @@ def test_async_saver_job_parented_to_submit(model_setup, tmp_path):
     manager.save that enqueued the snapshot."""
     cfg, plan, state, jmesh = model_setup
     with obs.enabled() as tracer:
-        mgr = CheckpointManager(
-            tmp_path / "ck", plan, async_save=True, save_interval=1
-        )
+        mgr = CheckpointManager(tmp_path / "ck", plan, policy=CheckpointPolicy(
+            async_save=True, save_interval=1))
         mgr.save(state, 10)
         mgr.wait()
         mgr.close()
@@ -395,7 +395,8 @@ def test_trainer_step_phases_in_order():
 def test_chrome_export_valid_and_consistent(model_setup, tmp_path):
     cfg, plan, state, jmesh = model_setup
     with obs.enabled() as tracer:
-        mgr = CheckpointManager(tmp_path / "ck", plan, async_save=False)
+        mgr = CheckpointManager(tmp_path / "ck", plan, policy=CheckpointPolicy(
+            async_save=False))
         mgr.save(state, 10)
         mgr.restore(jmesh, step=10)
         mgr.close()

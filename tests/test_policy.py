@@ -1,22 +1,21 @@
 """CheckpointPolicy: the consolidated checkpointing-knob object.
 
-Covers validation in ``__post_init__``, the codec-tag shorthand, the
-legacy-kwargs deprecation shim on both ``CheckpointManager`` and
-``Trainer.create``, and the error cases the shim must keep loud (unknown
-keyword names, mixing ``policy=`` with legacy knobs).
+Covers validation in ``__post_init__``, the codec-tag shorthand, and
+``policy=`` as the one spelling on both ``CheckpointManager`` and
+``Trainer.create``: a loose checkpoint keyword, an old knob name or a typo,
+is Python's own ``TypeError``.
 """
 
-import jax
 import pytest
 
 from repro.ckpt import CheckpointManager, CheckpointPolicy
-from repro.ckpt.policy import LEGACY_KNOBS, policy_from_legacy_kwargs
-from repro.configs import ParallelismConfig, get_config, reduced
+from repro.configs import ParallelismConfig, TrainConfig, get_config, reduced
 from repro.core.codec import CodecPolicy
 from repro.core.layout import MeshSpec
 from repro.dist.sharding import make_plan, vocab_multiple
 from repro.launch.mesh import make_mesh
 from repro.models import build_model
+from repro.train.trainer import Trainer
 
 
 @pytest.fixture(scope="module")
@@ -89,27 +88,6 @@ def test_lossy_params_require_opt_in():
     assert p.codec.params == "int8:b256"
 
 
-# ------------------------------------------------------------------- shim
-def test_legacy_knobs_cover_every_policy_field():
-    # the shim accepts exactly the policy's fields — adding a knob to the
-    # policy automatically extends the legacy surface, never silently drops
-    assert "save_mode" in LEGACY_KNOBS
-    assert "codec" in LEGACY_KNOBS
-
-
-def test_shim_warns_and_maps():
-    with pytest.warns(DeprecationWarning, match="deprecated"):
-        p = policy_from_legacy_kwargs(
-            {"keep_last": 7, "save_mode": "delta"}, where="here"
-        )
-    assert p.keep_last == 7 and p.save_mode == "delta"
-
-
-def test_shim_unknown_name_raises():
-    with pytest.raises(TypeError, match="kep_last"):
-        policy_from_legacy_kwargs({"kep_last": 7}, where="here")
-
-
 # ----------------------------------------------------- manager integration
 def test_manager_accepts_policy(tmp_path, plan):
     pol = CheckpointPolicy(
@@ -128,27 +106,26 @@ def test_manager_accepts_policy(tmp_path, plan):
         mgr.close()
 
 
-def test_manager_legacy_kwargs_warn_and_work(tmp_path, plan):
-    with pytest.warns(DeprecationWarning):
-        mgr = CheckpointManager(
-            tmp_path / "ck", plan, keep_last=5, async_save=False
+@pytest.mark.parametrize(
+    "make,name",
+    [
+        (CheckpointManager, "kep_last"),
+        (CheckpointManager, "keep_last"),
+        (Trainer.create, "keep_last"),
+    ],
+    ids=["manager-typo", "manager-old-knob", "trainer-old-knob"],
+)
+def test_manager_rejects_unknown_kwarg(tmp_path, plan, make, name):
+    if make is CheckpointManager:
+        args, kw = (tmp_path / "ck", plan), {}
+    else:
+        args = (
+            reduced(get_config("smollm-360m")), ParallelismConfig(),
+            TrainConfig(total_steps=10), make_mesh((1, 1), ("data", "model")),
         )
-    try:
-        assert mgr.keep_last == 5 and mgr.codec is None
-    finally:
-        mgr.close()
-
-
-def test_manager_rejects_policy_plus_legacy(tmp_path, plan):
-    with pytest.raises(TypeError, match="not both"):
-        CheckpointManager(
-            tmp_path / "ck", plan, policy=CheckpointPolicy(), keep_last=2
-        )
-
-
-def test_manager_rejects_unknown_kwarg(tmp_path, plan):
-    with pytest.raises(TypeError, match="unexpected keyword"):
-        CheckpointManager(tmp_path / "ck", plan, kep_last=2)
+        kw = dict(batch_size=2, seq_len=16, ckpt_dir=str(tmp_path / "ck"))
+    with pytest.raises(TypeError, match=f"unexpected keyword argument '{name}'"):
+        make(*args, **kw, **{name: 2})
 
 
 def test_manager_default_policy(tmp_path, plan):
@@ -160,10 +137,7 @@ def test_manager_default_policy(tmp_path, plan):
 
 
 # ----------------------------------------------------- trainer integration
-def test_trainer_accepts_policy_and_shims_legacy(tmp_path):
-    from repro.configs import TrainConfig
-    from repro.train.trainer import Trainer
-
+def test_trainer_accepts_policy(tmp_path):
     cfg = reduced(get_config("smollm-360m"))
     tcfg = TrainConfig(total_steps=10)
     jmesh = make_mesh((1, 1), ("data", "model"))
@@ -175,19 +149,3 @@ def test_trainer_accepts_policy_and_shims_legacy(tmp_path):
     assert tr.manager.policy is pol
     assert tr.manager.save_interval == 4
     tr.manager.close()
-
-    with pytest.warns(DeprecationWarning):
-        tr2 = Trainer.create(
-            cfg, ParallelismConfig(), tcfg, jmesh,
-            batch_size=2, seq_len=16, ckpt_dir=str(tmp_path / "b"),
-            save_interval=6, async_save=False,
-        )
-    assert tr2.manager.save_interval == 6
-    tr2.manager.close()
-
-    with pytest.raises(TypeError, match="not both"):
-        Trainer.create(
-            cfg, ParallelismConfig(), tcfg, jmesh,
-            batch_size=2, seq_len=16, ckpt_dir=str(tmp_path / "c"),
-            policy=pol, save_interval=6,
-        )

@@ -34,6 +34,7 @@ from repro.core import (
 from repro.core.patterns import ParamSpec
 from repro.core.plan import ResumeMode, TargetSpec
 from repro.ckpt.manager import CheckpointManager
+from repro.ckpt.policy import CheckpointPolicy
 from repro.ckpt.restore import state_from_stream, state_from_ucp
 from repro.ckpt.saver import write_distributed
 from repro.dist.sharding import ShardingPlan, make_plan, vocab_multiple
@@ -331,7 +332,8 @@ def matrix_sources(matrix_cfg, tmp_path_factory):
             plan = make_plan(matrix_cfg, lm.registry, parallel, mesh)
             state = init_state(lm.init(jax.random.PRNGKey(0)))
             root = tmp_path_factory.mktemp("src")
-            mgr = CheckpointManager(root / "ck", plan, async_save=False)
+            mgr = CheckpointManager(root / "ck", plan, policy=CheckpointPolicy(
+                async_save=False))
             mgr.save(state, 10)
             cache[key] = (root / "ck", plan, state)
         return cache[key]
@@ -350,7 +352,7 @@ def test_reshard_matrix(matrix_cfg, matrix_sources, tmp_path,
     axes = tuple(tgt_mesh)
     jmesh = make_mesh((1,) * len(axes), axes)
 
-    mgr = CheckpointManager(ck_dir, src_plan, async_save=False)
+    mgr = CheckpointManager(ck_dir, src_plan, policy=CheckpointPolicy(async_save=False))
     before = sorted(p for p in ck_dir.rglob("*") if p.is_file())
     restored, info = mgr.restore(jmesh, target_plan=tgt_plan)
     assert info.mode.value == expect, info.reason
@@ -431,7 +433,8 @@ def test_crash_mid_stream_falls_back_to_via_ucp(tmp_path, monkeypatch):
     }
     plan_src = ShardingPlan(mesh, dict(specs))
     snap = _random_state(specs, seed=11)
-    mgr = CheckpointManager(tmp_path / "ck", plan_src, async_save=False)
+    mgr = CheckpointManager(tmp_path / "ck", plan_src, policy=CheckpointPolicy(
+        async_save=False))
     write_distributed(snap, plan_src, 10, mgr.step_dir(10), engine=mgr.engine)
     tgt = {
         "w": uniform_param_spec("w", (8, 6), [DimSpec(), DimSpec(("data",))]),

@@ -13,6 +13,7 @@ from repro.configs import ParallelismConfig, TrainConfig, get_config, reduced
 from repro.core.layout import MeshSpec
 from repro.core.plan import ResumeMode
 from repro.ckpt.manager import CheckpointManager
+from repro.ckpt.policy import CheckpointPolicy
 from repro.dist.sharding import make_plan, vocab_multiple
 from repro.launch.mesh import make_mesh
 from repro.models import build_model
@@ -27,7 +28,8 @@ def _mk_trainer(tmp, **parallel_kw):
     tcfg = TrainConfig(warmup_steps=2, total_steps=50)
     return Trainer.create(
         cfg, parallel, tcfg, jmesh, batch_size=4, seq_len=24,
-        ckpt_dir=str(tmp / "ck"), save_interval=4, async_save=False,
+        ckpt_dir=str(tmp / "ck"),
+        policy=CheckpointPolicy(save_interval=4, async_save=False),
     )
 
 
