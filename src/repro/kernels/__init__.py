@@ -1,13 +1,12 @@
 """Pallas TPU kernels for the framework's compute hot-spots.
 
-Each kernel package has three layers:
-
-* ``kernel.py`` — ``pl.pallas_call`` + explicit BlockSpec VMEM tiling
-* ``ops.py``    — jitted public wrapper in model tensor layouts
-* ``ref.py``    — pure-jnp oracle the kernel is validated against
+Each kernel package has a ``kernel.py`` (``pl.pallas_call`` with explicit
+BlockSpec VMEM tiling) and an ``ops.py`` (the public wrapper in model
+tensor layouts); ``flash_attention`` and ``block_quant`` also keep a
+``ref.py``, the pure-jnp oracle they are validated against.  The SSD
+intra-chunk pair (``ssd_scan``) is validated against ``ssd_chunked``'s own
+jnp term, which it replaces on a TPU.
 
 On this CPU container kernels run under ``interpret=True``; on a TPU
-runtime the same calls compile to Mosaic.  The dry-run lowers the jnp
-reference path (Pallas does not lower on the CPU backend) — see
-EXPERIMENTS.md §Roofline for how kernel-level wins are accounted.
+runtime the same calls compile to Mosaic.
 """

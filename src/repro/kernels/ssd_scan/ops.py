@@ -1,36 +1,54 @@
-"""Jitted public wrapper for the SSD chunk-scan kernel."""
+"""Public wrapper of the SSD intra-chunk kernel pair: one differentiable
+call in the model's layouts, and the tiling rule that says where the TPU
+can run it."""
 
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 
-from .kernel import ssd_scan_fwd
+from .kernel import LANES, head_block, intra_bwd, intra_fwd
 
-__all__ = ["ssd_scan"]
+__all__ = ["ssd_intra", "fits"]
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(
-    x: jax.Array,   # [B, S, H, P]  (model layout)
-    dt: jax.Array,  # [B, S, H]
-    a: jax.Array,   # [H]
-    b: jax.Array,   # [B, S, G, N]
-    c: jax.Array,   # [B, S, G, N]
-    *,
-    chunk: int = 256,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Returns (y [B,S,H,P], h_final [B,H,P,N]); broadcasts groups → heads."""
-    bsz, s, h, p = x.shape
-    g = b.shape[2]
-    rep = h // g
-    bb = jnp.repeat(b, rep, axis=2).transpose(0, 2, 1, 3)
-    cc = jnp.repeat(c, rep, axis=2).transpose(0, 2, 1, 3)
-    y, hT = ssd_scan_fwd(
-        x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1), a, bb, cc,
-        chunk=chunk, interpret=interpret,
+def fits(chunk: int, heads: int, groups: int, head_dim: int, state: int) -> bool:
+    """Whether the kernel's blocks obey the TPU's tiling rule at these
+    widths: the chunk a multiple of 128 (it is the lane dim of every
+    ``[Q, Q]`` tile), each head's lanes inside one 128-lane window, C and B
+    split per group along 128-lane blocks, and a legal head block."""
+    return (
+        chunk % LANES == 0
+        and (head_dim % LANES == 0 or LANES % head_dim == 0)
+        and (groups == 1 or state % LANES == 0)
+        and head_block(heads, groups, head_dim, chunk)[1]
     )
-    return y.transpose(0, 2, 1, 3), hT
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _intra(cum, c, b, xdt, interpret):
+    return intra_fwd(cum, c, b, xdt, interpret=interpret)
+
+
+def _intra_fwd(cum, c, b, xdt, interpret):
+    return intra_fwd(cum, c, b, xdt, interpret=interpret), (cum, c, b, xdt)
+
+
+def _intra_bwd(interpret, res, dy):
+    cum, c, b, xdt = res
+    dcum, dc, db, dx = intra_bwd(cum, c, b, xdt, dy, interpret=interpret)
+    return dcum, dc.astype(c.dtype), db.astype(b.dtype), dx
+
+
+_intra.defvjp(_intra_fwd, _intra_bwd)
+
+
+def ssd_intra(cum, c, b, xdt, *, interpret: bool = False) -> jax.Array:
+    """``(C·Bᵀ ⊙ L) · (dt·x)`` per chunk, differentiable in every input.
+
+    ``cum`` ``[B, nc, Q, H]`` float32 (inclusive cumsum of dt·A within each
+    chunk), C and B ``[B, nc, Q, G, N]`` in the compute dtype (groups not
+    repeated over heads), dt·x ``[B, nc, Q, H, P]`` float32.  Returns
+    ``[B, nc, Q, H, P]`` float32."""
+    return _intra(cum, c, b, xdt, interpret)

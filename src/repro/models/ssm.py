@@ -5,10 +5,12 @@ chunk the recurrence is expressed as masked matmuls (MXU work), and only a
 short ``lax.scan`` over chunk boundary states remains sequential.  This is
 the adaptation story for this architecture — no CUDA-style selective-scan
 kernel is needed; the matmul-rich form *is* the hardware-appropriate
-algorithm.  ``repro.kernels.ssd_scan`` provides the Pallas kernel of the
-inner chunk computation; :func:`ssd_chunked` is the pure-jnp reference and
-the dry-run lowering; :func:`ssd_recurrent` is the O(S) oracle used by
-tests.
+algorithm.  The chunk's quadratic term, ``(C·Bᵀ ⊙ L) · (dt·x)``, is one
+Pallas kernel pair on one TPU chip (``repro.kernels.ssd_scan``: forward
+and backward, every per-head ``[Q, Q]`` tile kept in VMEM, C·Bᵀ formed once
+per group) wherever the widths meet its tiling rule; elsewhere (CPU, a
+step partitioned over several chips, chunks not a multiple of 128, odd
+widths) :func:`ssd_chunked` computes it as whole-tensor jnp.  :func:`ssd_recurrent` is the O(S) oracle used by tests.
 
 Shapes follow the paper: x [B,S,H,P] (P = head dim), dt [B,S,H],
 A [H] (negative), B/C [B,S,G,N] (G groups broadcast over heads, N = state).
@@ -19,16 +21,45 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+import repro.obs as obs
+from repro.kernels.ssd_scan.ops import fits as intra_kernel_fits
+from repro.kernels.ssd_scan.ops import ssd_intra
+
 __all__ = ["ssd_chunked", "ssd_recurrent", "ssm_decode_step", "causal_conv1d", "conv_decode_step"]
 
 
 def _broadcast_groups(bc: jax.Array, heads: int) -> jax.Array:
-    """[B,S,G,N] → [B,S,H,N] by repeating groups."""
-    b, s, g, n = bc.shape
+    """[..., G, N] → [..., H, N] by repeating groups."""
+    *lead, g, n = bc.shape
     rep = heads // g
-    return jnp.broadcast_to(bc[:, :, :, None, :], (b, s, g, rep, n)).reshape(
-        b, s, heads, n
+    return jnp.broadcast_to(bc[..., :, None, :], (*lead, g, rep, n)).reshape(
+        *lead, heads, n
     )
+
+
+def _one_tpu() -> bool:
+    """The process drives one TPU chip.  Over more devices the step is
+    partitioned, and XLA cannot partition a Mosaic kernel by itself."""
+    devices = jax.devices()
+    return len(devices) == 1 and devices[0].platform == "tpu"
+
+
+def _intra_jnp(cum, cq, bq, xdt):
+    """The intra-chunk term as whole-tensor jnp, for shapes and backends
+    the kernel does not take.  Layouts as :func:`ssd_intra`'s.
+
+    L[i,j] = exp(cum_i - cum_j) for i >= j (segment decay), else 0.  The
+    mask goes in before the exponential: above the diagonal cum_i - cum_j
+    is positive and overflows float32 at the published chunk (256) and
+    A/dt init, and a masked inf turns the gradient into 0 · inf = NaN."""
+    h, chunk = cum.shape[-1], cum.shape[2]
+    cq = _broadcast_groups(cq, h).astype(jnp.float32)
+    bq = _broadcast_groups(bq, h).astype(jnp.float32)
+    seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]      # [B,nc,Q,Q,H]
+    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
+    l_mask = jnp.exp(jnp.where(tri[None, None, :, :, None], seg, -jnp.inf))
+    cb = jnp.einsum("bcqhn,bckhn->bcqkh", cq, bq)
+    return jnp.einsum("bcqkh,bckhp->bcqhp", cb * l_mask, xdt)
 
 
 def ssd_recurrent(x, dt, a, bmat, cmat, *, h0=None):
@@ -66,38 +97,38 @@ def ssd_chunked(x, dt, a, bmat, cmat, *, chunk: int, h0=None):
     Finite forward and backward for any chunk length and any ``dt·A <= 0``.
     Its parts carry the named scopes ``ssd.intra``, ``ssd.states``,
     ``ssd.scan`` and ``ssd.inter``, so a device trace can attribute their
-    time.
+    time.  The intra-chunk term takes the Pallas kernel in a process that
+    drives one TPU chip, when the widths meet its tiling rule, else
+    whole-tensor jnp; each traced call bumps the counter
+    ``ssd.intra_kernel`` or ``ssd.intra_fallback``.
     """
     bsz, s, h, p = x.shape
-    n = bmat.shape[-1]
+    g, n = bmat.shape[-2:]
     if s % chunk:
         raise ValueError(f"seq {s} not divisible by chunk {chunk}")
     nc = s // chunk
-    bmat = _broadcast_groups(bmat, h)
-    cmat = _broadcast_groups(cmat, h)
 
-    # reshape to chunks: [B, nc, Q, ...]
+    # reshape to chunks: [B, nc, Q, ...]; B and C keep their G groups
     xq = x.reshape(bsz, nc, chunk, h, p)
     dtq = dt.reshape(bsz, nc, chunk, h)
-    bq = bmat.reshape(bsz, nc, chunk, h, n)
-    cq = cmat.reshape(bsz, nc, chunk, h, n)
+    bq = bmat.reshape(bsz, nc, chunk, g, n)
+    cq = cmat.reshape(bsz, nc, chunk, g, n)
     da = (dtq * a[None, None, None, :]).astype(jnp.float32)  # [B,nc,Q,H]
 
     cum = jnp.cumsum(da, axis=2)                      # inclusive cumsum
     total = cum[:, :, -1, :]                          # [B,nc,H]
 
     # ---- intra-chunk (quadratic in chunk length; pure matmul) -------------
-    # L[i,j] = exp(cum_i - cum_j) for i >= j (segment decay), else 0.  The
-    # mask goes in before the exponential: above the diagonal cum_i - cum_j
-    # is positive and overflows float32 at the published chunk (256) and
-    # A/dt init, and a masked inf turns the gradient into 0 · inf = NaN.
     with jax.named_scope("ssd.intra"):
-        seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]      # [B,nc,Q,Q,H]
-        tri = jnp.tril(jnp.ones((chunk, chunk), bool))
-        l_mask = jnp.exp(jnp.where(tri[None, None, :, :, None], seg, -jnp.inf))
-        cb = jnp.einsum("bcqhn,bckhn->bcqkh", cq.astype(jnp.float32), bq.astype(jnp.float32))
         xdt = xq.astype(jnp.float32) * dtq[..., None]
-        y_intra = jnp.einsum("bcqkh,bckhp->bcqhp", cb * l_mask, xdt)
+        if _one_tpu() and intra_kernel_fits(chunk, h, g, p, n):
+            obs.add("ssd.intra_kernel")
+            y_intra = ssd_intra(cum, cq, bq, xdt)
+        else:
+            obs.add("ssd.intra_fallback")
+            y_intra = _intra_jnp(cum, cq, bq, xdt)
+    bq = _broadcast_groups(bq, h)
+    cq = _broadcast_groups(cq, h)
 
     # ---- chunk boundary states --------------------------------------------
     # state contribution of chunk c: sum_j exp(total - cum_j) dt_j x_j B_j^T

@@ -135,6 +135,8 @@ COUNTERS: dict[str, str] = {
     "serve.changed_shards": "shards that changed across a publication",
     "serve.publications": "publications delivered",
     "serve.syncs": "fleet reader syncs completed",
+    "ssd.intra_fallback": "ssd_chunked traced with the intra-chunk term as whole-tensor jnp",
+    "ssd.intra_kernel": "ssd_chunked traced with the intra-chunk term as the Pallas kernel pair",
     # -- dynamic families --------------------------------------------------
     # save.<mode> (saver/drain: f"save.{result.mode}")
     "save.delta": "saves that took the delta path",

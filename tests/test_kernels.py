@@ -10,8 +10,8 @@ import pytest
 
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.ssd_scan.ops import ssd_scan
-from repro.kernels.ssd_scan.ref import ssd_ref
+from repro.kernels.ssd_scan.ops import ssd_intra
+from repro.models import ssm
 
 KEY = jax.random.PRNGKey(0)
 
@@ -80,49 +80,115 @@ def test_flash_attention_matches_model_reference():
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-5)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def _intra_inputs(b, nc, q, h, g, n, p, dtype, large_da, key):
+    """cum, C, B, dt·x in the layouts ``ssd_chunked`` hands the kernel.
+    ``large_da`` takes dt·A from the corners of Mamba-2's published init
+    (A up to 16, dt up to 0.1): over a chunk of 256 the decay exponent
+    then spans ≈400, whose exponential overflows float32 above the
+    diagonal unless the mask goes in first."""
+    ks = jax.random.split(key, 6)
+    if large_da:
+        a = -jnp.linspace(1.0, 16.0, h)
+        dt = jnp.geomspace(1e-3, 1e-1, h) * jnp.exp(0.1 * jax.random.normal(ks[0], (b, nc, q, h)))
+    else:
+        a = -jnp.exp(jax.random.normal(ks[0], (h,)))
+        dt = 0.05 * jax.nn.softplus(jax.random.normal(ks[1], (b, nc, q, h)))
+    cum = jnp.cumsum(dt * a, axis=2)
+    c = jax.random.normal(ks[2], (b, nc, q, g, n)).astype(dtype)
+    bm = jax.random.normal(ks[3], (b, nc, q, g, n)).astype(dtype)
+    x = jax.random.normal(ks[4], (b, nc, q, h, p)).astype(dtype)
+    xdt = x.astype(jnp.float32) * dt[..., None]
+    w = jax.random.normal(ks[5], xdt.shape)
+    return (cum, c, bm, xdt), w
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
 @pytest.mark.parametrize(
-    "b,s,h,p,g,n,chunk",
+    "b,nc,q,h,g,n,p,dtype,large_da",
     [
-        (1, 32, 2, 8, 1, 16, 8),
-        (2, 64, 4, 16, 2, 8, 16),
-        (1, 64, 6, 8, 3, 32, 32),
-        (1, 128, 2, 32, 1, 8, 64),
+        (1, 2, 128, 4, 1, 128, 64, jnp.float32, False),   # G = 1, two heads a lane window
+        (1, 2, 128, 4, 1, 128, 64, jnp.bfloat16, False),
+        (1, 1, 128, 4, 2, 128, 64, jnp.float32, False),   # G = 2
+        (1, 1, 128, 4, 2, 128, 64, jnp.bfloat16, False),
+        (2, 1, 256, 2, 1, 128, 64, jnp.float32, False),   # the published chunk
+        (1, 1, 256, 4, 2, 128, 128, jnp.bfloat16, False),  # one head a lane window
+        (1, 1, 128, 8, 2, 32, 32, jnp.float32, False),    # four heads a lane window
+        (1, 2, 256, 4, 1, 64, 64, jnp.float32, True),     # |dt·A| of the published init
+        (1, 1, 256, 4, 2, 128, 64, jnp.bfloat16, True),
+        (1, 1, 256, 24, 1, 128, 64, jnp.bfloat16, False),  # mamba2-130m's widths
     ],
 )
-def test_ssd_scan_sweep(b, s, h, p, g, n, chunk, dtype):
-    ks = jax.random.split(KEY, 5)
-    x = jax.random.normal(ks[0], (b, s, h, p)).astype(dtype)
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h))).astype(jnp.float32)
-    a = -jnp.exp(jax.random.normal(ks[2], (h,)))
-    bm = jax.random.normal(ks[3], (b, s, g, n)).astype(dtype)
-    cm = jax.random.normal(ks[4], (b, s, g, n)).astype(dtype)
-    y, hT = ssd_scan(x, dt, a, bm, cm, chunk=chunk, interpret=True)
-    rep = h // g
-    yr, hr = ssd_ref(
-        x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1), a,
-        jnp.repeat(bm, rep, 2).transpose(0, 2, 1, 3),
-        jnp.repeat(cm, rep, 2).transpose(0, 2, 1, 3),
-    )
-    tol = dict(atol=5e-2, rtol=5e-2) if dtype == jnp.bfloat16 else dict(atol=5e-4, rtol=1e-4)
-    np.testing.assert_allclose(
-        np.asarray(y, np.float32),
-        np.asarray(yr.transpose(0, 2, 1, 3), np.float32), **tol,
-    )
-    np.testing.assert_allclose(np.asarray(hT), np.asarray(hr), atol=5e-3, rtol=5e-3)
+def test_ssd_intra_kernel_matches_jnp_term(b, nc, q, h, g, n, p, dtype, large_da):
+    """The kernel pair against ``ssd_chunked``'s jnp intra term: y and the
+    gradient of every input through the custom VJP.
+
+    Tolerance, as a share of the largest entry: float32 runs the same
+    arithmetic in another order (1e-5).  With bfloat16 C and B the kernel
+    feeds the MXU bfloat16 operands, as a TPU's DEFAULT precision does for
+    the jnp term's float32 einsums, while the jnp term on the CPU keeps
+    float32 (2e-2)."""
+    args, w = _intra_inputs(b, nc, q, h, g, n, p, dtype, large_da, jax.random.PRNGKey(q + h + n))
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+
+    def loss(f):
+        return lambda *t: jnp.sum(f(*t) * w)
+
+    kernel = lambda *t: ssd_intra(*t, interpret=True)
+    assert _rel(kernel(*args), ssm._intra_jnp(*args)) < tol
+    got = jax.grad(loss(kernel), argnums=range(4))(*args)
+    want = jax.grad(loss(ssm._intra_jnp), argnums=range(4))(*args)
+    for name, u, v, arg in zip(("cum", "C", "B", "dt·x"), got, want, args):
+        assert u.dtype == arg.dtype, name
+        assert _rel(u, v) < tol, name
 
 
-def test_ssd_scan_chunk_invariance():
-    b, s, h, p, g, n = 1, 64, 2, 8, 1, 8
-    ks = jax.random.split(KEY, 5)
-    x = jax.random.normal(ks[0], (b, s, h, p))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
-    a = -jnp.exp(jax.random.normal(ks[2], (h,)))
-    bm = jax.random.normal(ks[3], (b, s, g, n))
-    cm = jax.random.normal(ks[4], (b, s, g, n))
-    outs = [
-        np.asarray(ssd_scan(x, dt, a, bm, cm, chunk=c, interpret=True)[0])
-        for c in (8, 16, 32, 64)
-    ]
-    for o in outs[1:]:
-        np.testing.assert_allclose(o, outs[0], atol=1e-4)
+LEGAL = dict(chunk=256, h=24, g=1, p=64, n=128)
+
+
+@pytest.mark.parametrize(
+    "one_tpu,shape,path",
+    [
+        (True, {}, "kernel"),
+        (True, dict(chunk=64), "fallback"),           # chunk not a multiple of 128
+        (True, dict(p=48), "fallback"),               # a head straddles lane windows
+        (True, dict(g=2, n=64), "fallback"),          # C and B not split on 128 lanes
+        (True, dict(h=6, g=2, p=32), "fallback"),     # no legal head block
+        (False, {}, "fallback"),                      # not one TPU chip
+    ],
+)
+def test_ssd_chunked_dispatches_intra_kernel(monkeypatch, one_tpu, shape, path):
+    """``ssd_chunked`` takes the kernel exactly on one TPU chip at widths
+    that meet its tiling rule, and counts the path it traced."""
+    import repro.obs as obs
+
+    d = {**LEGAL, **shape}
+    monkeypatch.setattr(ssm, "_one_tpu", lambda: one_tpu)
+    x = jax.ShapeDtypeStruct((1, 2 * d["chunk"], d["h"], d["p"]), jnp.bfloat16)
+    dt = jax.ShapeDtypeStruct((1, 2 * d["chunk"], d["h"]), jnp.float32)
+    a = jax.ShapeDtypeStruct((d["h"],), jnp.float32)
+    bc = jax.ShapeDtypeStruct((1, 2 * d["chunk"], d["g"], d["n"]), jnp.bfloat16)
+    with obs.enabled() as tracer:
+        jaxpr = jax.make_jaxpr(
+            lambda *t: ssm.ssd_chunked(*t, chunk=d["chunk"]))(x, dt, a, bc, bc)
+    counters = tracer.counters()
+    other = "fallback" if path == "kernel" else "kernel"
+    assert counters.get(f"ssd.intra_{path}") == 1
+    assert f"ssd.intra_{other}" not in counters
+    assert ("pallas_call" in str(jaxpr)) == (path == "kernel")
+
+
+@pytest.mark.parametrize(
+    "platform,count,want", [("tpu", 1, True), ("tpu", 4, False), ("cpu", 1, False)]
+)
+def test_intra_kernel_needs_one_tpu_chip(monkeypatch, platform, count, want):
+    """Over several chips XLA would have to partition the Mosaic kernel,
+    which it cannot: the step then keeps the jnp term."""
+    import types
+
+    monkeypatch.setattr(jax, "devices", lambda: [types.SimpleNamespace(platform=platform)] * count)
+    assert ssm._one_tpu() is want

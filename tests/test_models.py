@@ -175,6 +175,34 @@ def test_property_ssd_chunked_equals_recurrent(chunk, heads_per_group, g):
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=5e-4)
 
 
+def test_ssd_chunked_with_intra_kernel_equals_recurrent(monkeypatch):
+    """The kernel path of ``ssd_chunked`` (as on a TPU, the kernel run in
+    interpret mode) against the recurrence: two chunks of 128, one group
+    of 128 state over two heads of 64.
+
+    Tolerance: both sides float32 over 256 tokens; they differ by
+    summation order alone, 1e-5 of the largest entry."""
+    import functools
+
+    from repro.kernels.ssd_scan.ops import ssd_intra
+    from repro.models import ssm
+
+    monkeypatch.setattr(ssm, "_one_tpu", lambda: True)
+    monkeypatch.setattr(ssm, "ssd_intra", functools.partial(ssd_intra, interpret=True))
+    b, s, h, p, g, n = 1, 256, 2, 64, 1, 128
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    x = jax.random.normal(ks[0], (b, s, h, p))
+    dt = 0.1 * jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
+    a = -jnp.exp(jax.random.normal(ks[2], (h,)))
+    bm = jax.random.normal(ks[3], (b, s, g, n))
+    cm = jax.random.normal(ks[4], (b, s, g, n))
+    y1, h1 = ssd_recurrent(x, dt, a, bm, cm)
+    y2, h2 = ssd_chunked(x, dt, a, bm, cm, chunk=128)
+    for got, want in ((y2, y1), (h2, h1)):
+        got, want = np.asarray(got), np.asarray(want)
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * np.abs(want).max())
+
+
 def _published_ssd_inputs(b, s, p, n, key):
     """Four heads at the corners of Mamba-2's published init: A in [1, 16],
     dt = softplus(proj + dt_bias) with dt_bias placing dt in [1e-3, 1e-1],

@@ -139,3 +139,36 @@ def test_block_dequant_kernel_compiles(one_chip):
         count=n, use_kernel=True,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def ssd_intra_shapes(one_chip):
+    """The SSD intra-chunk kernel's operands at mamba2-130m's widths and
+    training shape: 16 rows of 2048 tokens in chunks of 256, 24 heads of 64
+    in one group, state 128, bfloat16 compute."""
+    sh = NamedSharding(one_chip, P())
+    b, nc, q, h, g, n, p = 16, 8, 256, 24, 1, 128, 64
+    return (
+        _sds((b, nc, q, h), jnp.float32, sh),
+        _sds((b, nc, q, g, n), jnp.bfloat16, sh),
+        _sds((b, nc, q, g, n), jnp.bfloat16, sh),
+        _sds((b, nc, q, h, p), jnp.float32, sh),
+    )
+
+
+def test_ssd_intra_kernel_compiles(ssd_intra_shapes):
+    from repro.kernels.ssd_scan.ops import fits, ssd_intra
+
+    assert fits(256, 24, 1, 64, 128)
+    compiled = jax.jit(ssd_intra).lower(*ssd_intra_shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_intra_backward_kernel_compiles(ssd_intra_shapes):
+    from repro.kernels.ssd_scan.ops import ssd_intra
+
+    def loss(cum, c, b, xdt):
+        return jnp.sum(ssd_intra(cum, c, b, xdt))
+
+    compiled = jax.jit(jax.grad(loss, argnums=range(4))).lower(*ssd_intra_shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
